@@ -18,6 +18,11 @@ import time
 from pathlib import Path
 from typing import Dict, Optional
 
+from repro.kernels.backend import enable_compile_cache
+
+# Every benchmark imports this module before its first compile.
+enable_compile_cache()
+
 from repro.core import EnvConfig, NGPQuantEnv, SearchConfig, hero_search
 from repro.core.baselines import caq_proxy_baseline, ptq_baseline, qat_baseline
 from repro.core.ddpg import DDPGConfig

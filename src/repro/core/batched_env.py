@@ -93,12 +93,14 @@ class BatchedQuantEnv:
         env: NGPQuantEnv,
         bcfg: BatchedEnvConfig = BatchedEnvConfig(),
         sharded: Optional[bool] = None,
+        mesh=None,
     ):
         """`sharded=None` auto-enables device-parallel population scoring
         when the host exposes more than one jax device (K policies split
         over a ("pop",) mesh, see repro.distributed.population); True/False
-        force it. Sharded and single-device paths produce identical metrics
-        (integer-exact cache stats either way)."""
+        force it. `mesh` picks the ("pop",) mesh of a sharded env (default:
+        every local device). Sharded and single-device paths produce
+        identical metrics (integer-exact cache stats either way)."""
         self.env = env
         self.bcfg = bcfg
         cfg = env.cfg
@@ -169,13 +171,13 @@ class BatchedQuantEnv:
             self.sharded = False
         if self.sharded:
             self._mse_batch = shard_population(
-                jax.vmap(_proxy_mse, in_axes=(None, 0, 0, 0)),
+                jax.vmap(_proxy_mse, in_axes=(None, 0, 0, 0)), mesh=mesh,
                 broadcast_argnums=(0,),
             )
             # Fully fused latency model so the whole per-policy evaluation
             # lives on its shard; for the NeuRex target the numbers match
             # the memoized host path (integer-exact stats, f32 compose).
-            self._lat_sharded = shard_population(jax.vmap(lat_fn))
+            self._lat_sharded = shard_population(jax.vmap(lat_fn), mesh=mesh)
         else:
             self._mse_batch = jax.jit(
                 jax.vmap(_proxy_mse, in_axes=(None, 0, 0, 0))

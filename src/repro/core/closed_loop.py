@@ -68,7 +68,8 @@ DEFAULT_HV_REF = (1.0, -5.0, 1.0)
 @dataclasses.dataclass(frozen=True)
 class SceneScale:
     """Env-building knobs shared by every scene of a run (mirrors the
-    benchmark scales; `tiny` exists for the test suite)."""
+    benchmark scales; `tiny` exists for the test suite, `paper` is the
+    published Instant-NGP field with its depth-like knobs cut)."""
 
     image_hw: int = 24
     n_train_views: int = 5
@@ -82,10 +83,52 @@ class SceneScale:
     finetune_steps: int = 8
     trace_rays: int = 256
     proxy_rays: int = 256
+    base_res: int = 4
+    sh_degree: int = 3
+
+    # Fields added after checkpoints were written: left out of the
+    # fingerprint while they hold their defaults, so every earlier run's
+    # fingerprint (and checkpoint) stays valid.
+    _LATE_FIELDS = ("base_res", "sh_degree")
+
+    def ngp_config(self):
+        """The `NGPConfig` this scale builds (geo_feat 15, F=2)."""
+        from repro.nerf.hash_encoding import HashEncodingConfig
+        from repro.nerf.ngp import NGPConfig
+
+        return NGPConfig(
+            hash=HashEncodingConfig(
+                n_levels=self.n_levels, log2_table_size=self.log2_table,
+                base_resolution=self.base_res, max_resolution=self.max_res,
+            ),
+            hidden_dim=self.hidden, color_hidden_dim=self.hidden,
+            geo_feat_dim=15, sh_degree=self.sh_degree,
+        )
+
+    def fingerprint(self) -> Dict:
+        fp = dataclasses.asdict(self)
+        for name in self._LATE_FIELDS:
+            if fp[name] == SceneScale.__dataclass_fields__[name].default:
+                del fp[name]
+        return fp
 
     @staticmethod
     def quick() -> "SceneScale":
         return SceneScale()
+
+    @staticmethod
+    def paper() -> "SceneScale":
+        """The paper's field at its published widths
+        (`configs/ngp.py:paper()`: 16 levels, F=2, T=2^19, resolutions
+        16..2048, 64-wide MLPs, SH degree 4). Only depth is cut from the
+        Blender-synthetic setup (800x800 frames, 100 train / 200 test
+        views): see `PAPER_DEPTH_CUTS`."""
+        return SceneScale(
+            image_hw=64, n_train_views=8, n_test_views=2, n_levels=16,
+            log2_table=19, max_res=2048, hidden=64, n_samples=32,
+            train_steps=300, finetune_steps=8, trace_rays=256,
+            proxy_rays=512, base_res=16, sh_degree=4,
+        )
 
     @staticmethod
     def standard() -> "SceneScale":
@@ -105,6 +148,21 @@ class SceneScale:
         )
 
 
+# What `SceneScale.paper()` cuts, each beside the published setting it
+# stands in for (Blender-synthetic scenes as Instant-NGP trains them).
+# Depth-like quantities only; every width is the paper's.
+PAPER_DEPTH_CUTS = {
+    "image_hw": "800 (Blender-synthetic frame side)",
+    "n_train_views": "100 (Blender-synthetic train split)",
+    "n_test_views": "200 (Blender-synthetic test split)",
+    "n_samples": "up to 1024 march steps per ray (Instant-NGP)",
+    "train_steps": "trained to convergence, tens of thousands of steps",
+    "finetune_steps": "QAT retraining to recovery (length not published)",
+    "trace_rays": "full frames (simulator workload trace)",
+    "proxy_rays": "full frames (population proxy render)",
+}
+
+
 def build_scene_env(
     scene: str,
     scale: SceneScale = SceneScale(),
@@ -120,8 +178,6 @@ def build_scene_env(
     (e.g. the roofline family) ignore it.
     """
     from repro.nerf.dataset import make_dataset
-    from repro.nerf.hash_encoding import HashEncodingConfig
-    from repro.nerf.ngp import NGPConfig
     from repro.nerf.render import RenderConfig
     from repro.nerf.scenes import SceneConfig
     from repro.nerf.train import TrainConfig, train_ngp
@@ -130,14 +186,7 @@ def build_scene_env(
         name=scene, image_hw=scale.image_hw,
         n_train_views=scale.n_train_views, n_test_views=scale.n_test_views,
     ))
-    cfg = NGPConfig(
-        hash=HashEncodingConfig(
-            n_levels=scale.n_levels, log2_table_size=scale.log2_table,
-            base_resolution=4, max_resolution=scale.max_res,
-        ),
-        hidden_dim=scale.hidden, color_hidden_dim=scale.hidden,
-        geo_feat_dim=15, sh_degree=3,
-    )
+    cfg = scale.ngp_config()
     rcfg = RenderConfig(n_samples=scale.n_samples)
     tcfg = TrainConfig(steps=scale.train_steps, batch_rays=512, lr=5e-3,
                        seed=seed)
@@ -239,7 +288,7 @@ class ClosedLoopConfig:
             "scenes": list(self.scenes),
             "budget_fracs": [float(f) for f in self.budget_fracs],
             "seed": self.seed,
-            "scale": dataclasses.asdict(self.scale),
+            "scale": self.scale.fingerprint(),
             "n_iterations": self.n_iterations,
             "population": self.population,
             "agent_fraction": self.agent_fraction,

@@ -171,12 +171,26 @@ class SubprocessWorker:
     isolation — a segfaulting scorer kills the worker, not the sweep. The
     job travels as JSON (config + spec) through a temp file; the result
     comes back on a marker line of stdout (`repro.distributed.worker_main`).
+
+    CPU backends only. An accelerator belongs to one process at a time,
+    and this (parent) process holds it once it has trained or scored
+    anything, so a child would fail or hang waiting for the chip: the
+    constructor refuses instead.
     """
 
     MARKER = "HERO_CELL_OUTPUT:"
 
     def __init__(self, payload_fn: Callable[[CellSpec], Dict],
                  name: str = "proc-0"):
+        import jax
+
+        backend = jax.default_backend()
+        if backend != "cpu":
+            raise RuntimeError(
+                f"subprocess workers cannot share this process's {backend} "
+                "device (a device belongs to one process); use "
+                "--worker-kind thread (or inline) instead"
+            )
         self.payload_fn = payload_fn
         self.name = name
         self._proc: Optional[subprocess.Popen] = None

@@ -12,7 +12,7 @@ broadcast argument and every output leaf) in a `shard_map` over a 1-D
     think about divisibility;
   - broadcast arguments (e.g. the shared NGP weights for the PSNR proxy)
     are replicated via an empty PartitionSpec;
-  - on a single-device host the wrapper degrades to the plain vmapped
+  - on a single-device mesh the wrapper degrades to the plain vmapped
     call — same numbers, no sharding machinery in the way.
 
 Both halves of a population evaluation fit this contract as pure jax:
@@ -31,11 +31,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.4.35 (pinned in pyproject); kept soft for odd builds
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover
-    shard_map = None
 
 from repro.launch.mesh import make_mesh_compat
 
@@ -76,7 +71,7 @@ def shard_population(
     n_shards = int(np.prod(mesh.devices.shape))
     bcast = frozenset(broadcast_argnums)
 
-    if n_shards == 1 or shard_map is None:
+    if n_shards == 1:
         jitted = jax.jit(fn)
 
         def call_single(*args):
@@ -96,9 +91,9 @@ def shard_population(
         key = len(args)
         if key not in sharded:
             sharded[key] = jax.jit(
-                shard_map(
+                jax.shard_map(
                     fn, mesh=mesh, in_specs=specs(args),
-                    out_specs=P(POP_AXIS), check_rep=False,
+                    out_specs=P(POP_AXIS), check_vma=False,
                 )
             )
         batched = [i for i in range(len(args)) if i not in bcast]

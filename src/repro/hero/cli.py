@@ -25,6 +25,9 @@ def search_main(argv=None) -> int:
     a Pareto frontier (+ BENCH_search.json) out."""
     import jax
 
+    from repro.kernels.backend import enable_compile_cache
+
+    enable_compile_cache()
     from repro.core.closed_loop import (
         ClosedLoopConfig,
         HeroSearchRun,
@@ -76,7 +79,8 @@ def search_main(argv=None) -> int:
     ap.add_argument("--worker-kind", default="thread",
                     choices=("thread", "inline", "subprocess"),
                     help="worker isolation: threads share the process "
-                         "(default), subprocess survives segfaulting cells")
+                         "(default), subprocess survives segfaulting cells "
+                         "(CPU backends only: a chip has one process)")
     ap.add_argument("--chaos", type=int, default=None, metavar="SEED",
                     help="fault-injection drill: seed a FaultPlan over the "
                          "sweep's cells (worker kills / transient errors) "
@@ -374,6 +378,9 @@ def _parse_bits(s: Optional[str], n_units: int) -> Optional[Sequence[int]]:
 def serve_main(argv=None) -> int:
     """Compile (or load) a QuantArtifact and drive the batched render
     service against it."""
+    from repro.kernels.backend import enable_compile_cache
+
+    enable_compile_cache()
     from repro.core.closed_loop import SceneScale, build_scene_env
     from repro.hero.artifact import QuantArtifact, compile_artifact
     from repro.nerf.dataset import make_dataset

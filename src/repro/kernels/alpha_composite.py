@@ -12,7 +12,7 @@ chunks would have contributed at most `t_eps` per channel, so the numerics
 match the dense walk to that tolerance.
 
   alpha_i = 1 - exp(-sigma_i * delta_i)
-  T_i     = prod_{j<i} (1 - alpha_j)      (exclusive)
+  T_i     = prod_{j<i} (1 - alpha_j) = exp(-sum_{j<i} sigma_j delta_j)
   color   = sum_i T_i * alpha_i * rgb_i ; acc = sum_i T_i * alpha_i
 
 Prefer `repro.kernels.ops.alpha_composite` (the canonical entry): it adds
@@ -33,9 +33,15 @@ from repro.kernels.backend import resolve_interpret
 
 
 def _composite_kernel(sigma_ref, rgb_ref, delta_ref, color_ref, acc_ref,
-                      trans_ref, done_ref, *, n_s, early_stop, t_eps):
-    """Block: (br rays, bs samples). Grid axis 1 walks sample chunks."""
+                      trans_ref, done_ref, *, early_stop, t_eps):
+    """Block: (br rays, bs samples). Grid axis 1 walks sample chunks.
+
+    The exclusive in-chunk transmittance is exp(-(sigma*delta) @ U) with U
+    the strictly upper-triangular (bs, bs) ones matrix: one MXU matmul
+    gives every sample's exclusive optical-depth prefix sum (Mosaic has
+    no cumprod). `rgb_ref` is channel-major (3, br, bs)."""
     s = pl.program_id(1)
+    bs = sigma_ref.shape[1]
 
     @pl.when(s == 0)
     def _init():
@@ -45,20 +51,25 @@ def _composite_kernel(sigma_ref, rgb_ref, delta_ref, color_ref, acc_ref,
         done_ref[...] = jnp.zeros_like(done_ref)
 
     def _step():
-        sigma = sigma_ref[...]  # (br, bs)
-        delta = delta_ref[...]
-        alpha = 1.0 - jnp.exp(-sigma * delta)  # (br, bs)
-        keep = 1.0 - alpha
-        # exclusive cumprod along samples within the chunk
-        cum = jnp.cumprod(keep, axis=1)
-        excl = jnp.concatenate([jnp.ones_like(cum[:, :1]), cum[:, :-1]], axis=1)
-        T = trans_ref[...] * excl  # (br, bs) transmittance at each sample
-        w = T * alpha  # weights
-        color_ref[...] += jnp.einsum(
-            "rs,rsc->rc", w, rgb_ref[...], preferred_element_type=jnp.float32
-        )
+        tau = sigma_ref[...] * delta_ref[...]  # (br, bs) optical depth
+        alpha = 1.0 - jnp.exp(-tau)
+        row = jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 1)
+        upper = (row < col).astype(jnp.float32)
+        excl = jax.lax.dot_general(
+            tau, upper, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )  # (br, bs): sum_{j<i} tau_j
+        w = trans_ref[...] * jnp.exp(-excl) * alpha  # weights
+        for c in range(3):
+            color_ref[:, c:c + 1] += jnp.sum(
+                w * rgb_ref[c], axis=1, keepdims=True
+            )
         acc_ref[...] += jnp.sum(w, axis=1, keepdims=True)
-        trans_ref[...] = trans_ref[...] * cum[:, -1:]
+        trans_ref[...] = trans_ref[...] * jnp.exp(
+            -jnp.sum(tau, axis=1, keepdims=True)
+        )
         if early_stop:
             # All rays in the block saturated -> skip the remaining chunks.
             done_ref[...] = (
@@ -96,18 +107,18 @@ def alpha_composite(
                   constant_values=1e4)
     dl = jnp.pad(jnp.pad(delta, ((0, 0), (0, ps))), ((0, pr), (0, 0)),
                  constant_values=1.0)
-    rg = jnp.pad(rgb, ((0, pr), (0, ps), (0, 0)))
+    rg = jnp.moveaxis(jnp.pad(rgb, ((0, pr), (0, ps), (0, 0))), 2, 0)
     Rp, Sp = R + pr, S + ps
     n_s = Sp // bs
 
     color, acc = pl.pallas_call(
         functools.partial(
-            _composite_kernel, n_s=n_s, early_stop=early_stop, t_eps=t_eps
+            _composite_kernel, early_stop=early_stop, t_eps=t_eps
         ),
         grid=(Rp // br, n_s),
         in_specs=[
             pl.BlockSpec((br, bs), lambda r, s: (r, s)),
-            pl.BlockSpec((br, bs, 3), lambda r, s: (r, s, 0)),
+            pl.BlockSpec((3, br, bs), lambda r, s: (0, r, s)),
             pl.BlockSpec((br, bs), lambda r, s: (r, s)),
         ],
         out_specs=[

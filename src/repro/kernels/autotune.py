@@ -213,7 +213,10 @@ def default_candidates(m: int, k: int, n: int) -> List[Tuple[int, int, int]]:
 # them. Block choice never changes numerics (the march is an exact
 # {0,1} mask), only speed.
 
-RAY_MARCH_DEFAULT: Tuple[int, int, int] = (128, 8, 512)
+# The smallest blocks the TPU tiling accepts: br rays on sublanes (8),
+# bs samples on lanes (128), and a bt table chunk that holds a whole
+# 32^3 grid's (x, y) columns in one one-hot matmul.
+RAY_MARCH_DEFAULT: Tuple[int, int, int] = (8, 128, 1024)
 
 
 def _ray_march_entries(table: Optional[dict], key: Optional[str]) -> list:
@@ -312,11 +315,13 @@ def measure_ray_march_entry(
 
 
 def ray_march_candidates(r: int, s: int, g: int) -> List[Tuple[int, int, int]]:
-    """Small candidate grid clipped to the padded problem."""
-    rp = -(-max(r, 1) // 128) * 128
-    brs = sorted({min(o, rp) for o in (128, 256, 512)})
-    bss = sorted({min(o, s) for o in (4, 8, 16) if o <= max(s, 4)} or {4})
-    gp = g * g
+    """Small candidate grid of chip-legal blocks (br a multiple of 8, bs
+    and bt multiples of 128), clipped to the padded problem."""
+    rp = -(-max(r, 1) // 8) * 8
+    brs = sorted({min(o, rp) for o in (8, 16, 32)})
+    sp = -(-max(s, 1) // 128) * 128
+    bss = sorted({min(o, sp) for o in (128, 256)})
+    gp = -(-(g * g) // 128) * 128
     bts = sorted({min(o, gp) for o in (256, 512, 1024)})
     return [(br, bs, bt) for br in brs for bs in bss for bt in bts]
 

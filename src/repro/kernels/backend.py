@@ -12,13 +12,33 @@ from __future__ import annotations
 
 import os
 import platform
+from pathlib import Path
 from typing import Dict, Optional
 
 import jax
 
+# The persistent compile cache's fixed home when JAX_COMPILATION_CACHE_DIR
+# is unset: `.jax_cache/` at the checkout root (listed in .gitignore). A
+# fixed path, because the path is part of what a later run must find.
+CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    JAX_COMPILATION_CACHE_DIR, when set, is the cache (JAX reads it
+    itself) and nothing else is configured. Otherwise the cache lives in
+    `CHECKOUT_CACHE_DIR`. Call before the first compile: JAX decides at
+    its first compile whether a cache is in use."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_CACHE_DIR))
+    return str(CHECKOUT_CACHE_DIR)
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:

@@ -1,14 +1,17 @@
 """Pallas TPU kernel: hash-table gather as a one-hot MXU matmul.
 
 TPUs have no efficient per-lane random gather; for VMEM-resident hash
-levels (T <= 2^14) the classic trick re-expresses the 8-corner gather as
-(points*8, T_tile) one-hot x (T_tile, F) matmul, accumulated over T tiles
-(DESIGN.md §3). The one-hot never leaves VMEM; the MXU does the "gather".
-Features are padded to the 128-lane boundary by the wrapper.
+levels (T <= 2^14 = `ONEHOT_MAX_ROWS`) the classic trick re-expresses the
+8-corner gather as (points*8, T_tile) one-hot x (T_tile, F) matmul,
+accumulated over T tiles (DESIGN.md §3). The one-hot never leaves VMEM;
+the MXU does the "gather". Features are padded to the 128-lane boundary
+by the wrapper. The work is P*T MACs per call, so larger tables are
+refused here: `repro.kernels.ops.hash_encode` sends them to XLA's gather.
 
 Prefer `repro.kernels.ops.hash_gather` (the canonical entry): it adds the
-XLA-take reference fallback. This raw entry auto-detects `interpret`
-(compiled on TPU, interpret-mode elsewhere) when left at None.
+XLA-take reference fallback and the domain routing. This raw entry
+auto-detects `interpret` (compiled on TPU, interpret-mode elsewhere) when
+left at None.
 """
 from __future__ import annotations
 
@@ -21,6 +24,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.backend import resolve_interpret
+
+# The kernel's domain: tables of at most this many rows. Past it the
+# one-hot work (P*T MACs) and its VMEM temporaries grow without bound.
+ONEHOT_MAX_ROWS = 2 ** 14
 
 
 def _hash_gather_kernel(idx_ref, table_ref, out_ref, acc_ref, *, bt, n_t):
@@ -36,8 +43,11 @@ def _hash_gather_kernel(idx_ref, table_ref, out_ref, acc_ref, *, bt, n_t):
     local = idx - base  # (bp, 1)
     cols = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], bt), 1)
     onehot = (cols == local).astype(table_ref.dtype)  # (bp, bt)
+    # HIGHEST: an f32 table must not round through one bf16 MXU pass; the
+    # one-hot products are then exact and the gather is bit-identical.
     acc_ref[...] += jax.lax.dot_general(
         onehot, table_ref[...], (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
 
@@ -54,10 +64,17 @@ def hash_gather(
     bt: int = 1024,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    """Returns (P, F) = table[indices] via one-hot matmuls."""
+    """Returns (P, F) = table[indices] via one-hot matmuls. Raises for a
+    table of more than `ONEHOT_MAX_ROWS` rows (outside the domain)."""
     interpret = resolve_interpret(interpret)
     P = indices.shape[0]
     T, F = table.shape
+    if T > ONEHOT_MAX_ROWS:
+        raise ValueError(
+            f"hash_gather: a {T}-row table is outside the one-hot kernel's "
+            f"domain (<= {ONEHOT_MAX_ROWS} rows); use "
+            "repro.kernels.ops.hash_gather, which routes it to XLA's gather"
+        )
     pf = (-F) % 128
     pt = (-T) % bt
     pp = (-P) % bp

@@ -13,7 +13,11 @@ they never fall back to the reference.
 """
 from __future__ import annotations
 
+from collections import Counter
+
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels import ref
 from repro.kernels import autotune as _autotune
@@ -25,7 +29,10 @@ from repro.kernels.decode_attention_kernel import (
 from repro.kernels.flash_attention_kernel import (
     flash_attention as _flash_pallas,
 )
-from repro.kernels.hash_encoding_kernel import hash_gather as _hash_pallas
+from repro.kernels.hash_encoding_kernel import (
+    ONEHOT_MAX_ROWS,
+    hash_gather as _hash_pallas,
+)
 from repro.kernels.quant_matmul import (
     quant_matmul as _qmm_pallas,
     quant_matmul_packed as _qmm_packed_pallas,
@@ -34,9 +41,17 @@ from repro.kernels.ray_march import ray_march as _ray_march_pallas
 from repro.quant.packing import tile_layout_bk as _tile_layout_bk
 
 
-def _resolve(use_pallas):
+# How the "auto" calls resolved, counted per (entry, "compiled" |
+# "reference") at trace time: a run on the chip asserts from this that no
+# "auto" call fell back to the reference.
+AUTO_RESOLVED: Counter = Counter()
+
+
+def _resolve(use_pallas, entry: str):
     if use_pallas == "auto":
-        return _on_tpu(), not _on_tpu()
+        run = _on_tpu()
+        AUTO_RESOLVED[(entry, "compiled" if run else "reference")] += 1
+        return run, not run
     return bool(use_pallas), True  # explicit True => interpret off-TPU
 
 
@@ -59,7 +74,7 @@ def _fill_blocks(kw, m, k, n, bits, fixed_bk=None):
 
 
 def quant_matmul(x_codes, w_codes, sx, sw, zx, use_pallas="auto", **kw):
-    run, interpret = _resolve(use_pallas)
+    run, interpret = _resolve(use_pallas, "quant_matmul")
     if not run:
         return ref.quant_matmul_ref(x_codes, w_codes, sx, sw, zx)
     kw = _fill_blocks(kw, x_codes.shape[0], x_codes.shape[1],
@@ -79,7 +94,7 @@ def quant_matmul_packed(x_codes, wq, sx, sw, zx, use_pallas="auto", **kw):
     kernel's K-tile; the reference unpacks with the pure-jnp codec
     (layout-aware) and reuses `quant_matmul_ref`. Missing block sizes
     come from the measured autotune table."""
-    run, interpret = _resolve(use_pallas)
+    run, interpret = _resolve(use_pallas, "quant_matmul_packed")
     if not run:
         return ref.quant_matmul_packed_ref(x_codes, wq, sx, sw, zx)
     layout = getattr(wq, "layout", "planar")
@@ -92,31 +107,59 @@ def quant_matmul_packed(x_codes, wq, sx, sw, zx, use_pallas="auto", **kw):
     )
 
 
-def hash_encode(corner_idx, corner_w, table_cat, level_offsets,
+def hash_level_path(rows: int, use_pallas="auto") -> str:
+    """Which gather a hash level of `rows` table rows takes: "onehot" (the
+    Pallas MXU kernel, inside its <= 2^14-row domain) or "xla_gather"
+    (XLA's gather: large levels on any backend, every level on the
+    reference path). A static rule on table sizes, shared by the
+    dispatch below and by anything that reports the routing."""
+    run, _ = _resolve(use_pallas, "hash_gather")
+    return "onehot" if run and rows <= ONEHOT_MAX_ROWS else "xla_gather"
+
+
+def hash_encode(corner_idx, corner_w, table_cat, level_rows,
                 use_pallas="auto", **kw):
-    """Fused multi-level hash-grid encode: one gather over a concatenated
+    """Fused multi-level hash-grid encode: gathers over a concatenated
     table + trilinear interpolation.
 
     corner_idx    (L, B, 8) int32 — per-level in-table corner indices
     corner_w      (L, B, 8) f32   — matching trilinear weights
     table_cat     (T, F)    f32   — all level tables stacked row-wise
-    level_offsets (L,)      int32 — row offset of each level in table_cat
+    level_rows    L static ints   — each level's row count in table_cat
 
+    Each level inside the one-hot kernel's domain gathers from its own
+    static slice through `hash_gather`; every larger level goes through
+    ONE XLA gather over the concatenated table (`hash_level_path`).
     Returns (B, L*F) features in level-major column order — bit-identical
     to gathering each level's table separately and concatenating (pinned
-    by tests). One fused gather instead of L keeps the whole encode in a
-    single kernel dispatch and sidesteps the per-level dequantize-inside-
-    the-gather fusion pathology on CPU backends.
+    by tests).
     """
     L, B, C = corner_idx.shape
-    flat = (corner_idx + level_offsets[:, None, None]).reshape(-1)
-    vals = hash_gather(flat, table_cat, use_pallas=use_pallas, **kw)
-    vals = vals.reshape(L, B, C, -1)
+    rows = [int(r) for r in level_rows]
+    if len(rows) != L or sum(rows) != table_cat.shape[0]:
+        raise ValueError(f"level_rows {rows} do not split a "
+                         f"{table_cat.shape[0]}-row table into {L} levels")
+    offs = np.concatenate([[0], np.cumsum(rows)[:-1]]).astype(np.int32)
+    vals, big = [None] * L, []
+    for l, (off, r) in enumerate(zip(offs.tolist(), rows)):
+        if hash_level_path(r, use_pallas) == "onehot":
+            tab = jax.lax.slice_in_dim(table_cat, off, off + r)
+            vals[l] = hash_gather(corner_idx[l].reshape(-1), tab,
+                                  use_pallas=use_pallas, **kw)
+        else:
+            big.append(l)
+    if big:
+        flat = (corner_idx[np.asarray(big)]
+                + jnp.asarray(offs[big])[:, None, None]).reshape(-1)
+        got = ref.hash_gather_ref(flat, table_cat).reshape(len(big), B * C, -1)
+        for i, l in enumerate(big):
+            vals[l] = got[i]
+    vals = jnp.stack(vals).reshape(L, B, C, -1)
     feats = jnp.sum(vals * corner_w[..., None], axis=2)  # (L, B, F)
     return jnp.moveaxis(feats, 0, 1).reshape(B, -1)
 
 
-def fused_field_query(corner_idx, corner_w, table_cat, level_offsets,
+def fused_field_query(corner_idx, corner_w, table_cat, level_rows,
                       wq, act, use_pallas="auto", **kw):
     """hash_gather -> trilinear interp -> quantized matmul, the fused
     first-layer field query of `FastRenderEngine`'s integer path.
@@ -127,7 +170,7 @@ def fused_field_query(corner_idx, corner_w, table_cat, level_offsets,
     `wq` is the layer's `PackedTensor` (planar or tile-native). Returns
     the f32 pre-activation (B, N).
     """
-    enc = hash_encode(corner_idx, corner_w, table_cat, level_offsets,
+    enc = hash_encode(corner_idx, corner_w, table_cat, level_rows,
                       use_pallas=use_pallas)
     codes = jnp.clip(jnp.round(enc / act["sx"] + act["zx_f"]), 0.0,
                      act["qmax"])
@@ -139,7 +182,7 @@ def fused_field_query(corner_idx, corner_w, table_cat, level_offsets,
 def alpha_composite(sigma, rgb, delta, use_pallas="auto", **kw):
     """kw passes through to the kernel — notably `early_stop=True` enables
     the transmittance-based chunk skipping (ignored by the reference)."""
-    run, interpret = _resolve(use_pallas)
+    run, interpret = _resolve(use_pallas, "alpha_composite")
     if not run:
         return ref.alpha_composite_ref(sigma, rgb, delta)
     return _alpha_pallas(
@@ -153,7 +196,7 @@ def ray_march(occ, rays_o, rays_d, t, use_pallas="auto", **kw):
     renderer's sample points); the block choice never changes the mask.
     `t` must be non-decreasing for `early_stop=True` (the default);
     missing br/bs/bt come from the measured autotune table."""
-    run, interpret = _resolve(use_pallas)
+    run, interpret = _resolve(use_pallas, "ray_march")
     if not run:
         return ref.ray_march_ref(occ, rays_o, rays_d, t)
     if not all(b in kw for b in ("br", "bs", "bt")):
@@ -169,16 +212,15 @@ def ray_march(occ, rays_o, rays_d, t, use_pallas="auto", **kw):
 
 
 def hash_gather(indices, table, use_pallas="auto", **kw):
-    run, interpret = _resolve(use_pallas)
-    if not run:
+    """table[indices]: the one-hot kernel inside its domain, XLA's gather
+    for larger tables and on the reference path (`hash_level_path`)."""
+    if hash_level_path(table.shape[0], use_pallas) == "xla_gather":
         return ref.hash_gather_ref(indices, table)
-    return _hash_pallas(
-        indices, table, interpret=interpret and not _on_tpu(), **kw
-    )
+    return _hash_pallas(indices, table, interpret=not _on_tpu(), **kw)
 
 
 def decode_attention(q, k, v, length, use_pallas="auto", **kw):
-    run, interpret = _resolve(use_pallas)
+    run, interpret = _resolve(use_pallas, "decode_attention")
     if not run:
         return ref.decode_attention_ref(q, k, v, length)
     return _decode_pallas(
@@ -187,7 +229,7 @@ def decode_attention(q, k, v, length, use_pallas="auto", **kw):
 
 
 def flash_attention(q, k, v, causal=True, use_pallas="auto", **kw):
-    run, interpret = _resolve(use_pallas)
+    run, interpret = _resolve(use_pallas, "flash_attention")
     if not run:
         return ref.flash_attention_ref(q, k, v, causal=causal)
     return _flash_pallas(

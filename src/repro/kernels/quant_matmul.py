@@ -51,12 +51,14 @@ def _qmm_kernel(x_ref, w_ref, sx_ref, sw_ref, zx_ref, o_ref, acc_ref, *, n_k):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.int32)
-    w = w_ref[...].astype(jnp.int32)
+    # int8 operands straight into the MXU (the v5e MXU takes no int32
+    # operands), int32 accumulation via preferred_element_type.
+    w = w_ref[...]
     prod = jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
+        x_ref[...], w, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32,
     )
-    wsum = jnp.sum(w, axis=0, keepdims=True)  # (1, bn)
+    wsum = jnp.sum(w.astype(jnp.int32), axis=0, keepdims=True)  # (1, bn)
     acc_ref[...] += prod - zx_ref[0, 0] * wsum
 
     @pl.when(k == n_k - 1)
@@ -173,15 +175,15 @@ def _qmm_packed_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.int32)
     unpack = _unpack_tile_native if tile_native else _unpack_tile
     u = unpack(w_ref[...], bits, bk)
     q = u + off_ref[0, 0]
     row = jax.lax.broadcasted_iota(jnp.int32, q.shape, 0) + k * bk
     q = jnp.where(row < k_rows, q, 0)
-    w = jnp.clip(q, -128, 127)
+    w = jnp.clip(q, -128, 127)  # clip BEFORE narrowing: int8 cannot wrap
     prod = jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
+        x_ref[...], w.astype(jnp.int8), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32,
     )
     wsum = jnp.sum(w, axis=0, keepdims=True)  # (1, bn)
     acc_ref[...] += prod - zx_ref[0, 0] * wsum

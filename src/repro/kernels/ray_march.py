@@ -15,10 +15,12 @@ kernel's t_eps tolerance).
 The per-sample occupancy lookup is a gather with data-dependent indices;
 TPUs have no per-lane random gather, so (hash_encoding_kernel's trick)
 it is re-expressed as one-hot MXU matmuls: the (G, G, G) grid is viewed
-as (G*G, G) rows, a sample one-hot selects its (x, y) row against table
-chunks of `bt` rows (accumulated over a fori_loop so the one-hot never
-exceeds (br, bs, bt) in VMEM), and a second one-hot over the row's G
-z-entries selects the cell value.
+as a (G, G*G) matrix whose column x*G + y is the z-column of cell (x, y),
+each ray's (1, bs) sample row selects its columns with a (bt, bs) one-hot
+per table chunk of `bt` columns (so the one-hot never exceeds (bt, bs) in
+VMEM), and a mask over the G z-entries selects the cell value. Rays are
+walked by a fori_loop inside the block, which keeps every value 2-D with
+samples on the lane axis, as the chip's tiling wants.
 
 Semantics are EXACTLY `repro.kernels.ref.ray_march_ref` — a sample at
 o + d * t is active iff strictly inside the [-0.5, 0.5)^3 box and in an
@@ -50,10 +52,14 @@ _BIG = 3.0e38  # "never exits" sentinel, comfortably below f32 inf
 
 def _ray_march_kernel(t_ref, ro_ref, rd_ref, occ_ref, out_ref,
                       texit_ref, done_ref, *, g, bt, n_t, early_stop):
-    """Block: (br rays, bs samples). Grid axis 1 walks sample chunks."""
+    """Block: (br rays, bs samples). Grid axis 1 walks sample chunks.
+
+    Everything stays 2-D with samples on the lane axis: a fori_loop walks
+    the block's rays, and each ray's (1, bs) sample row is looked up by a
+    (G, bt) x (bt, bs) one-hot matmul per table chunk (Mosaic lowers no
+    3-D gathers or broadcasts of the (br, bs, 3) point cloud)."""
     s = pl.program_id(1)
-    o = ro_ref[...]  # (br, 3)
-    d = rd_ref[...]
+    br = ro_ref.shape[0]
 
     @pl.when(s == 0)
     def _init():
@@ -62,6 +68,8 @@ def _ray_march_kernel(t_ref, ro_ref, rd_ref, occ_ref, out_ref,
         # Degenerate axes (d ~ 0): the axis never bounds the ray when the
         # origin coordinate is inside, and the ray never enters at all
         # when it is outside.
+        o = ro_ref[...]  # (br, 3)
+        d = rd_ref[...]
         safe = jnp.abs(d) > 1e-12
         inv = 1.0 / jnp.where(safe, d, 1.0)
         t1 = (-0.5 - o) * inv
@@ -75,42 +83,47 @@ def _ray_march_kernel(t_ref, ro_ref, rd_ref, occ_ref, out_ref,
 
     def _step():
         t = t_ref[...]  # (1, bs)
-        pts = o[:, None, :] + d[:, None, :] * t[0, :, None]  # (br, bs, 3)
-        inside = jnp.all((pts > -0.5) & (pts < 0.5), axis=-1)  # (br, bs)
-        unit = jnp.clip(pts + 0.5, 0.0, 1.0)
-        cell = jnp.clip((unit * g).astype(jnp.int32), 0, g - 1)
-        row = cell[..., 0] * g + cell[..., 1]  # (br, bs) in [0, G*G)
-        iz = cell[..., 2]
+        bs = t.shape[1]
 
-        def gather_rows(c, acc):
-            # One-hot "gather" of each sample's (x, y) grid row: (br, bs,
-            # bt) x (bt, G) contraction, accumulated over table chunks.
-            rows = occ_ref[pl.ds(c * bt, bt), :]  # (bt, G)
-            local = row - c * bt
-            cols = jax.lax.broadcasted_iota(
-                jnp.int32, row.shape + (bt,), 2
+        def one_ray(r, carry):
+            o = ro_ref[pl.ds(r, 1), :]  # (1, 3)
+            d = rd_ref[pl.ds(r, 1), :]
+            inside = None
+            cell = []
+            for ax in range(3):
+                p = o[:, ax:ax + 1] + d[:, ax:ax + 1] * t  # (1, bs)
+                ins = (p > -0.5) & (p < 0.5)
+                inside = ins if inside is None else inside & ins
+                unit = jnp.clip(p + 0.5, 0.0, 1.0)
+                cell.append(jnp.clip((unit * g).astype(jnp.int32), 0, g - 1))
+            row = cell[0] * g + cell[1]  # (1, bs) in [0, G*G)
+            zrow = jnp.zeros((g, bs), jnp.float32)
+            for c in range(n_t):
+                # One-hot "gather" of each sample's (x, y) grid column:
+                # (G, bt) x (bt, bs) -> every sample's full z-column.
+                rows = jax.lax.broadcasted_iota(jnp.int32, (bt, bs), 0)
+                onehot = (rows == row - c * bt).astype(jnp.float32)
+                zrow = zrow + jax.lax.dot_general(
+                    occ_ref[:, c * bt:(c + 1) * bt], onehot,
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+            zs = jax.lax.broadcasted_iota(jnp.int32, (g, bs), 0)
+            val = jnp.sum(
+                jnp.where(zs == cell[2], zrow, 0.0), axis=0, keepdims=True
             )
-            onehot = (cols == local[:, :, None]).astype(jnp.float32)
-            return acc + jax.lax.dot_general(
-                onehot, rows, (((2,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
+            out_ref[pl.ds(r, 1), :] = (inside & (val > 0.5)).astype(
+                jnp.float32
             )
+            return carry
 
-        acc = jax.lax.fori_loop(
-            0, n_t, gather_rows,
-            jnp.zeros(row.shape + (g,), jnp.float32),
-        )  # (br, bs, G): each sample's full z-row
-        zcols = jax.lax.broadcasted_iota(jnp.int32, row.shape + (g,), 2)
-        val = jnp.sum(
-            acc * (zcols == iz[:, :, None]).astype(jnp.float32), axis=2
-        )
-        out_ref[...] = (inside & (val > 0.5)).astype(jnp.float32)
+        jax.lax.fori_loop(0, br, one_ray, 0)
         if early_stop:
             # t is non-decreasing: once this chunk's last sample sits
             # strictly past EVERY ray's box exit, all later samples are
             # outside -> later chunks write exact zeros.
             done_ref[...] = (
-                (t[0, -1] > jnp.max(texit_ref[...]))
+                (jnp.max(t) > jnp.max(texit_ref[...]))
                 .astype(jnp.float32).reshape(1, 1)
             )
 
@@ -136,18 +149,20 @@ def ray_march(
     rays_o: jnp.ndarray,  # (R, 3)
     rays_d: jnp.ndarray,  # (R, 3)
     t: jnp.ndarray,  # (S,) f32 sample depths, non-decreasing
-    br: int = 128,
-    bs: int = 8,
-    bt: int = 512,
+    br: int = 8,
+    bs: int = 128,
+    bt: int = 1024,
     interpret: Optional[bool] = None,
     early_stop: bool = True,
 ) -> jnp.ndarray:
     """Returns active (R, S) f32 {0, 1} — see `ref.ray_march_ref`."""
     interpret = resolve_interpret(interpret)
     g = occ.shape[0]
-    occ2d = occ.reshape(g * g, g)
+    # (G, G*G) with column x*G + y holding the z-column of cell (x, y).
+    occ_t = occ.reshape(g * g, g).T
+    bt = min(bt, -(-(g * g) // 128) * 128)  # never pad past one lane tile
     pt = (-(g * g)) % bt
-    occ2d = jnp.pad(occ2d, ((0, pt), (0, 0)))
+    occ_t = jnp.pad(occ_t, ((0, 0), (0, pt)))
     n_t = (g * g + pt) // bt
 
     R, S = rays_o.shape[0], t.shape[0]
@@ -170,7 +185,7 @@ def ray_march(
             pl.BlockSpec((1, bs), lambda r, s: (0, s)),
             pl.BlockSpec((br, 3), lambda r, s: (r, 0)),
             pl.BlockSpec((br, 3), lambda r, s: (r, 0)),
-            pl.BlockSpec((g * g + pt, g), lambda r, s: (0, 0)),
+            pl.BlockSpec((g, g * g + pt), lambda r, s: (0, 0)),
         ],
         out_specs=pl.BlockSpec((br, bs), lambda r, s: (r, s)),
         out_shape=jax.ShapeDtypeStruct((Rp, Sp), jnp.float32),
@@ -179,5 +194,5 @@ def ray_march(
             pltpu.VMEM((1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(tt, ro, rd, occ2d)
+    )(tt, ro, rd, occ_t)
     return out[:R, :S]

@@ -14,11 +14,10 @@ import jax
 
 
 def make_mesh_compat(shape, axes):
-    """jax.make_mesh across versions: AxisType landed in jax 0.5."""
-    if hasattr(jax.sharding, "AxisType"):
-        types = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=types)
-    return jax.make_mesh(shape, axes)
+    """`jax.make_mesh` with every axis in Auto sharding mode (the default
+    mode of the installed JAX is Explicit, which this code does not use)."""
+    types = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=types)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

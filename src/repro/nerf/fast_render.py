@@ -244,9 +244,9 @@ def repack_fused_pack(
     compute entries:
       "table_cat"       (sum_l T_l, F) f32 — every level table
                         dequantized and stacked row-wise, so the fused
-                        encode is ONE gather with no per-level
-                        dequantize inside the jitted hot path;
-      "table_off"       (L,) int32 — each level's row offset in the cat;
+                        encode has no per-level dequantize inside the
+                        jitted hot path (level row counts are static:
+                        `cfg.hash.level_entries`);
       "<name>::wq_tile" tile-native `PackedTensor` per packed layer (the
                         `kernels/repack.py` permutation the matmul
                         kernel unpacks with a single broadcast shift);
@@ -260,15 +260,11 @@ def repack_fused_pack(
         return dataclasses.replace(pack, layout=layout, compute={})
     bk = int(layout.split(":", 1)[1])
     compute: Dict[str, jnp.ndarray] = {}
-    tabs, offs, row = [], [], 0
+    tabs = []
     for l in range(len(pack.hash_tables)):
         t = pack.hash_tables[f"level_{l}"]
-        t = t.dequantize() if isinstance(t, PackedTensor) else t
-        tabs.append(t)
-        offs.append(row)
-        row += t.shape[0]
+        tabs.append(t.dequantize() if isinstance(t, PackedTensor) else t)
     compute["table_cat"] = jnp.concatenate(tabs, axis=0)
-    compute["table_off"] = jnp.asarray(offs, jnp.int32)
     for name, lyr in pack.layers.items():
         if "wq" in lyr:
             compute[f"{name}::wq_tile"] = repack_tile_native(lyr["wq"], bk)
@@ -360,7 +356,7 @@ def fused_ngp_apply(
     `CullPlan` for fixed sample points.
 
     With a repacked pack (`pack.compute` staged) the encode is the fused
-    one-gather `ops.hash_encode` over the staged concatenated table —
+    `ops.hash_encode` over the staged concatenated table —
     this keeps per-level `dequantize()` out of the jitted hot path, where
     XLA:CPU fuses it into every gather lane — and, on the kernel path,
     the first linear folds into `ops.fused_field_query`."""
@@ -374,15 +370,16 @@ def fused_ngp_apply(
             w = jnp.stack([w_ for _, w_ in per_level])
         else:
             idx, w = corner_data
-        cat, off = pack.compute["table_cat"], pack.compute["table_off"]
+        cat = pack.compute["table_cat"]
+        rows = tuple(cfg.hash.level_entries(l) for l in range(L))
         if pack.modes[0] == "int" and _use_kernels(use_pallas):
             lyr = pack.layers[names[0]]
             h = ops_fused_field_query(
-                idx, w, cat, off, _layer_wq(pack, names[0]), lyr,
+                idx, w, cat, rows, _layer_wq(pack, names[0]), lyr,
                 use_pallas=use_pallas,
             ) + lyr["b"]
         else:
-            enc = ops_hash_encode(idx, w, cat, off, use_pallas=use_pallas)
+            enc = ops_hash_encode(idx, w, cat, rows, use_pallas=use_pallas)
             h = _fused_linear(pack, 0, names[0], enc, use_pallas)
     else:
         # Storage-only pack (schema-v2 artifact loaded without repack):

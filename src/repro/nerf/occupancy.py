@@ -105,13 +105,17 @@ def bake_occupancy(
         np.asarray([0.0, 0.0, 1.0], np.float32), pts.shape
     )  # sigma is view-independent
 
+    # params are an argument, not a closure: closed over, the whole hash
+    # table would be baked into the program as constants (a cache entry
+    # of about the table's size, 64 MB at T=2^19).
     query = jax.jit(
-        lambda p, d: ngp_apply(params, p, d, cfg, spec)[0],
+        lambda q, p, d: ngp_apply(q, p, d, cfg, spec)[0],
     )
     sig = np.empty(pts.shape[0], np.float32)
     for s in range(0, pts.shape[0], chunk):
         sig[s : s + chunk] = np.asarray(
-            query(jnp.asarray(pts[s : s + chunk]), jnp.asarray(dirs[s : s + chunk]))
+            query(params, jnp.asarray(pts[s : s + chunk]),
+                  jnp.asarray(dirs[s : s + chunk]))
         )
 
     sig = jnp.asarray(sig.reshape(fine, fine, fine))

@@ -475,3 +475,35 @@ def test_sharded_two_device_subprocess_parity():
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "SHARDED_OK" in proc.stdout
+
+
+# ---------------------------------------------------------------------------
+# Scales: the paper widths, and fingerprints that older checkpoints match
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("name,sha", [
+    ("quick",
+     "5a7bf468582b71d06070dd54177ae0ab43e54545c993daa8a54bfb4c39609b42"),
+    ("standard",
+     "f3da6bec8c03608ae0ddb46f51903fc1e94f131eecaf4b94d2fd00a0abb66c75"),
+    ("tiny",
+     "a233b8c95a6df679fd34b8320a2bde9abb9aa3d81d70ed4176f15f64a65aa54d"),
+])
+def test_scene_scale_keeps_checkpoint_fingerprint(name, sha):
+    """Fields added to SceneScale (base_res, sh_degree) stay out of the
+    run fingerprint at their defaults: every existing scale keeps the
+    fingerprint its checkpoints were written with."""
+    import hashlib
+
+    fp = ClosedLoopConfig(scale=getattr(SceneScale, name)()).fingerprint()
+    got = hashlib.sha256(json.dumps(fp, sort_keys=True).encode()).hexdigest()
+    assert got == sha
+    assert "base_res" not in fp["scale"] and "sh_degree" not in fp["scale"]
+
+
+def test_paper_scale_builds_the_paper_widths():
+    from repro.configs.ngp import paper
+
+    scale = SceneScale.paper()
+    assert scale.ngp_config() == paper()
+    fp = ClosedLoopConfig(scale=scale).fingerprint()["scale"]
+    assert fp["base_res"] == 16 and fp["sh_degree"] == 4

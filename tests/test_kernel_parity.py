@@ -156,13 +156,12 @@ def _hash_inputs(L=3, B=37, T=64, F=2, seed=3):
     w = jnp.asarray(rng.dirichlet(np.ones(8), size=(L, B)), jnp.float32)
     tables = [jnp.asarray(rng.randn(T, F), jnp.float32) for _ in range(L)]
     cat = jnp.concatenate(tables, axis=0)
-    off = jnp.asarray([l * T for l in range(L)], jnp.int32)
-    return idx, w, tables, cat, off
+    return idx, w, tables, cat, (T,) * L
 
 
 def test_hash_encode_matches_per_level_gather():
-    idx, w, tables, cat, off = _hash_inputs()
-    got = ops.hash_encode(idx, w, cat, off, use_pallas=False)
+    idx, w, tables, cat, rows = _hash_inputs()
+    got = ops.hash_encode(idx, w, cat, rows, use_pallas=False)
     per_level = [
         jnp.sum(tables[l][idx[l]] * w[l][..., None], axis=1)
         for l in range(len(tables))
@@ -172,15 +171,15 @@ def test_hash_encode_matches_per_level_gather():
 
 
 def test_fused_field_query_matches_manual_pipeline():
-    idx, w, _, cat, off = _hash_inputs(L=4, B=29, T=32, F=2)
+    idx, w, _, cat, rows = _hash_inputs(L=4, B=29, T=32, F=2)
     K = 4 * 2
     wq = _packed(K, 16, 4, scale=0.03)
     wt = repack_tile_native(wq)
     act = {"sx": 0.05, "zx_f": 128.0, "qmax": 255.0, "off": 128,
            "zx": jnp.int32(0)}
-    got = ops.fused_field_query(idx, w, cat, off, wt, act, use_pallas=True)
+    got = ops.fused_field_query(idx, w, cat, rows, wt, act, use_pallas=True)
 
-    enc = ops.hash_encode(idx, w, cat, off, use_pallas=False)
+    enc = ops.hash_encode(idx, w, cat, rows, use_pallas=False)
     codes = jnp.clip(jnp.round(enc / act["sx"] + act["zx_f"]), 0.0,
                      act["qmax"])
     ci8 = (codes - act["off"]).astype(jnp.int8)
@@ -188,6 +187,55 @@ def test_fused_field_query_matches_manual_pipeline():
                                        act["zx"])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("use_pallas", [True, False])
+def test_hash_encode_routing_across_onehot_domain(use_pallas):
+    """Levels on both sides of the one-hot kernel's 2^14-row domain: small
+    levels take the kernel, larger ones XLA's gather, and the encode is
+    bit-identical to per-level `jnp.take` either way."""
+    from repro.kernels.hash_encoding_kernel import ONEHOT_MAX_ROWS
+
+    rows = (300, ONEHOT_MAX_ROWS, ONEHOT_MAX_ROWS + 1, 3 * ONEHOT_MAX_ROWS)
+    rng = np.random.RandomState(5)
+    B = 16
+    tables = [jnp.asarray(rng.randn(r, 2), jnp.float32) for r in rows]
+    idx = jnp.asarray(np.stack([rng.randint(0, r, size=(B, 8)) for r in rows]),
+                      jnp.int32)
+    # Pin the row extremes of every level: first, last, and (for the
+    # large levels) the boundary rows around 2^14.
+    idx = idx.at[:, 0, 0].set(0)
+    idx = idx.at[:, 0, 1].set(jnp.asarray([r - 1 for r in rows]))
+    w = jnp.asarray(rng.dirichlet(np.ones(8), size=(len(rows), B)),
+                    jnp.float32)
+    paths = [ops.hash_level_path(r, use_pallas) for r in rows]
+    if use_pallas:
+        assert paths == ["onehot", "onehot", "xla_gather", "xla_gather"]
+    else:
+        assert set(paths) == {"xla_gather"}
+    got = ops.hash_encode(idx, w, jnp.concatenate(tables), rows,
+                          use_pallas=use_pallas)
+    want = jnp.concatenate([
+        jnp.sum(jnp.take(tables[l], idx[l], axis=0) * w[l][..., None],
+                axis=1)
+        for l in range(len(rows))
+    ], axis=-1)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_raw_hash_gather_refuses_out_of_domain_table():
+    from repro.kernels.hash_encoding_kernel import (
+        ONEHOT_MAX_ROWS,
+        hash_gather,
+    )
+
+    idx = jnp.zeros((8,), jnp.int32)
+    with pytest.raises(ValueError, match="domain"):
+        hash_gather(idx, jnp.zeros((ONEHOT_MAX_ROWS + 1, 2)), interpret=True)
+    # ...while the canonical entry routes the same table to XLA's gather.
+    got = ops.hash_gather(idx, jnp.ones((ONEHOT_MAX_ROWS + 1, 2)),
+                          use_pallas=True)
+    np.testing.assert_array_equal(np.asarray(got), np.ones((8, 2)))
 
 
 # ---------------------------------------------------------------------------

@@ -401,3 +401,17 @@ def test_subprocess_worker_runs_real_cell(tmp_path):
     assert out.cell == spec.name and out.points
     assert out.policies_evaluated > 0
     w.close()
+
+
+@pytest.mark.parametrize("backend", ["tpu", "gpu"])
+def test_subprocess_worker_refuses_on_accelerator(monkeypatch, backend):
+    """A device belongs to one process: on an accelerator backend the
+    subprocess worker refuses before starting a child that would wait on
+    the chip, and its error names the worker kind that works."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    with pytest.raises(RuntimeError, match="--worker-kind thread"):
+        SubprocessWorker(lambda spec: {}, name="p0")
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    SubprocessWorker(lambda spec: {}, name="p0").close()  # CPU: allowed
