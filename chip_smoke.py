@@ -71,42 +71,11 @@ def check(ok: bool, what: str) -> None:
     print(f"[smoke] check ok: {what}", flush=True)
 
 
-# ---------------------------------------------------------------------------
-# Compile-time accounting from JAX's monitoring events
-# ---------------------------------------------------------------------------
-class CompileClock:
-    """Seconds JAX spent lowering and compiling top-level computations,
-    and persistent compile-cache hits/misses, summed since registration.
-    (Tracing is left out: nested jits record nested trace events, which
-    would count twice.)"""
-
-    EVENTS = (
-        "/jax/core/compile/jaxpr_to_mlir_module_duration",
-        "/jax/core/compile/backend_compile_duration",
-    )
-
-    def __init__(self):
-        import jax.monitoring as mon
-
-        self.seconds = 0.0
-        self.hits = 0
-        self.misses = 0
-        mon.register_event_duration_secs_listener(self._on_duration)
-        mon.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, duration, **_):
-        if event in self.EVENTS:
-            self.seconds += duration
-
-    def _on_event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-
 class Phase:
-    def __init__(self, name: str, clock: CompileClock, report: dict):
+    """Wall and compile seconds of one phase; `clock` is a
+    `repro.obs.CompileClock`."""
+
+    def __init__(self, name: str, clock, report: dict):
         self.name, self.clock, self.report = name, clock, report
 
     def __enter__(self):
@@ -400,6 +369,7 @@ def main(argv=None) -> int:
         return 2
     sys.path.insert(0, str(ROOT / "src"))
     from repro.kernels.backend import enable_compile_cache
+    from repro.obs import CompileClock
 
     cache_dir = enable_compile_cache()
     import jax

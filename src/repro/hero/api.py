@@ -15,6 +15,7 @@ from __future__ import annotations
 
 from typing import List, Optional, Sequence, Tuple, Union
 
+from repro import obs
 from repro.hero.artifact import QuantArtifact, compile_artifact
 from repro.hero.engine import EngineConfig, ServeEngine, serve_engine
 from repro.hero.service import RenderService, ServeConfig
@@ -124,8 +125,10 @@ def serve(
     `ServeEngine` (continuous batching across scenes, LRU artifact cache
     with `loader` on miss and `cache_bytes` eviction budget, streaming
     `poll()`). `cfg` is a `ServeConfig` (shared knobs) or, for the
-    engine, an `EngineConfig` directly.
+    engine, an `EngineConfig` directly. Garbage collections of the
+    process are recorded as `host.gc` spans from here on (`obs.install`).
     """
+    obs.install()
     if isinstance(artifacts, QuantArtifact):
         return _serve(artifacts, cfg or ServeConfig(), warmup=warmup)
     if isinstance(cfg, EngineConfig):

@@ -381,6 +381,7 @@ def serve_main(argv=None) -> int:
     from repro.kernels.backend import enable_compile_cache
 
     enable_compile_cache()
+    from repro import obs
     from repro.core.closed_loop import SceneScale, build_scene_env
     from repro.hero.artifact import QuantArtifact, compile_artifact
     from repro.nerf.dataset import make_dataset
@@ -416,6 +417,7 @@ def serve_main(argv=None) -> int:
                     help="also save the compiled artifact to this directory")
     ap.add_argument("--out", default="BENCH_serve.json")
     args = ap.parse_args(argv)
+    obs.install()
 
     scale = SceneScale.quick() if args.quick else SceneScale.standard()
     scenes = [s for s in (args.scenes or "").split(",") if s]

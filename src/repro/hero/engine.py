@@ -44,6 +44,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from repro import obs
 from repro.hero.scheduler import (
     AdmissionFull,
     ArtifactLoadError,
@@ -187,8 +188,9 @@ class FusedDeviceStep:
     object actually changes (reload).
     """
 
-    def __init__(self, cfg: EngineConfig):
+    def __init__(self, cfg: EngineConfig, recorder: obs.Recorder):
         self.cfg = cfg
+        self.obs = recorder  # the owning engine's spans and counters
         self._align = 128
         self._state: Dict[str, Dict] = {}
         assert cfg.compaction in ("march", "scatter"), cfg.compaction
@@ -276,9 +278,10 @@ class FusedDeviceStep:
             return None
         from repro.nerf.pose_cache import pose_cell_key
 
-        return (scene,) + pose_cell_key(
-            ro, rd, self._pose_grid.pos_cell, self._pose_grid.dir_cell
-        )
+        with self.obs.span("pose.key"):
+            return (scene,) + pose_cell_key(
+                ro, rd, self._pose_grid.pos_cell, self._pose_grid.dir_cell
+            )
 
     def note_pose_use(self, key) -> None:
         """Count ONE visit of the pose cell (called once per submitted
@@ -317,16 +320,27 @@ class FusedDeviceStep:
         no silently dropped samples, no host-side mask pass per step."""
         from repro.nerf.fast_render import _slot_march_impl
 
+        rec = self.obs
         while True:
-            color, need = _slot_march_impl(
-                artifact.params, artifact.pack, st["spec"], artifact.occ,
-                ro_s, rd_s, cfg=artifact.cfg, rcfg=st["rcfg"], mode="fused",
-                budget=st["budget"], use_pallas=self.cfg.use_pallas,
-                early_stop=self.cfg.early_stop,
-            )
-            if st["budget"] is None or int(need) <= st["budget"]:
-                return np.asarray(color)
-            need = int(need)
+            with rec.span("render.dispatch"):
+                color, need = _slot_march_impl(
+                    artifact.params, artifact.pack, st["spec"], artifact.occ,
+                    ro_s, rd_s, cfg=artifact.cfg, rcfg=st["rcfg"],
+                    mode="fused", budget=st["budget"],
+                    use_pallas=self.cfg.use_pallas,
+                    early_stop=self.cfg.early_stop,
+                )
+            budget = st["budget"]
+            with rec.span("render.wait"):
+                if budget is None:
+                    return np.asarray(color)
+                need = int(need)
+                if need <= budget:
+                    color = np.asarray(color)
+            if need <= budget:
+                rec.count("render.active_samples", need)
+                rec.count("render.budget_samples", budget)
+                return color
             cap = self.cfg.slot_rays * st["rcfg"].n_samples
             grown = int(
                 np.ceil(max(need * self.cfg.budget_headroom, need)
@@ -334,6 +348,78 @@ class FusedDeviceStep:
             )
             st["budget"] = min(grown, cap)
             st["retraces"] += 1
+
+    def _render_slot(self, st, artifact, it: WorkItem,
+                     ro: np.ndarray, rd: np.ndarray) -> np.ndarray:
+        """One live slot of `step_items` through its pose-cache tier."""
+        import jax.numpy as jnp
+
+        from repro.nerf.fast_render import _slot_plan_impl, _slot_warp_impl
+
+        rec = self.obs
+        with rec.span("render.slot", rid=it.rid, seq=it.seq) as sp:
+            kw = dict(
+                cfg=artifact.cfg, rcfg=st["rcfg"], mode="fused",
+                use_pallas=self.cfg.use_pallas, early_stop=self.cfg.early_stop,
+            )
+            with rec.span("render.stage"):
+                ro_s, rd_s = jnp.asarray(ro), jnp.asarray(rd)
+            cache = self._pose_cache
+            key = getattr(it, "pose_key", None)
+            entry = plan = None
+            tier = "march"
+            if cache is not None and key is not None:
+                from repro.nerf import pose_cache as pc
+
+                # Visits were counted at submit; a cell dropped between
+                # submit and step (scene eviction) restarts at one use.
+                entry = cache.get(key)
+                if entry is None:
+                    entry = cache.note_use(key)
+                plan = entry.plans.get(it.seq)
+                if plan is not None:
+                    if pc.ray_fingerprint(ro, rd) == plan.fp:
+                        tier = "hit"
+                    elif pc.warp_deviation(
+                        ro, rd, plan.ref_o, plan.ref_d, st["rcfg"],
+                    ) <= plan.margin:
+                        tier = "warp"
+                    else:
+                        plan = None  # drifted out of coverage: rebuild
+            sp.set(tier=tier)
+            if tier == "hit":
+                cache.hits += 1
+                with rec.span("render.dispatch"):
+                    out = _slot_plan_impl(
+                        artifact.params, artifact.pack, st["spec"],
+                        artifact.occ, ro_s, rd_s, plan.plan_row, **kw,
+                    )
+            elif tier == "warp":
+                cache.warps += 1
+                with rec.span("render.dispatch"):
+                    out = _slot_warp_impl(
+                        artifact.params, artifact.pack, st["spec"],
+                        artifact.occ, ro_s, rd_s, plan.inv_take, plan.take,
+                        plan.valid_cons, **kw,
+                    )
+            else:
+                if cache is not None and key is not None:
+                    cache.misses += 1
+                out = self._march_slot(st, artifact, ro_s, rd_s)
+                if (
+                    entry is not None
+                    and entry.uses >= self._pose_grid.build_after
+                ):
+                    from repro.nerf import pose_cache as pc
+
+                    with rec.span("pose.build"):
+                        cache.put_plan(key, it.seq, pc.build_warp_plan(
+                            artifact.occ, ro, rd, st["rcfg"],
+                            artifact.cfg, self._pose_grid.margin(artifact.occ),
+                        ))
+                return out
+            with rec.span("render.wait"):
+                return np.asarray(out)
 
     def step_items(
         self, scene: str, artifact, items: List[WorkItem],
@@ -348,72 +434,14 @@ class FusedDeviceStep:
         runs at the same fixed (slot_rays, 3) padded shape, so mixing
         tiers within a bucket never retraces anything.
         """
-        import jax.numpy as jnp
-
-        from repro.nerf.fast_render import _slot_plan_impl, _slot_warp_impl
-
         if self.cfg.compaction != "march":
             # Legacy scatter strategy has no tiers: one padded-bucket call.
             return np.asarray(self(scene, artifact, ro, rd))
         st = self._scene_state(scene, artifact)
         S = ro.shape[0]
         colors = np.zeros((S, ro.shape[1], 3), np.float32)
-        kw = dict(
-            cfg=artifact.cfg, rcfg=st["rcfg"], mode="fused",
-            use_pallas=self.cfg.use_pallas, early_stop=self.cfg.early_stop,
-        )
-        cache = self._pose_cache
         for slot, it in enumerate(items):
-            ro_s, rd_s = jnp.asarray(ro[slot]), jnp.asarray(rd[slot])
-            key = getattr(it, "pose_key", None)
-            entry = plan = None
-            tier = "march"
-            if cache is not None and key is not None:
-                from repro.nerf import pose_cache as pc
-
-                # Visits were counted at submit; a cell dropped between
-                # submit and step (scene eviction) restarts at one use.
-                entry = cache.get(key)
-                if entry is None:
-                    entry = cache.note_use(key)
-                plan = entry.plans.get(it.seq)
-                if plan is not None:
-                    if pc.ray_fingerprint(ro[slot], rd[slot]) == plan.fp:
-                        tier = "hit"
-                    elif pc.warp_deviation(
-                        ro[slot], rd[slot], plan.ref_o, plan.ref_d,
-                        st["rcfg"],
-                    ) <= plan.margin:
-                        tier = "warp"
-                    else:
-                        plan = None  # drifted out of coverage: rebuild
-            if tier == "hit":
-                cache.hits += 1
-                colors[slot] = np.asarray(_slot_plan_impl(
-                    artifact.params, artifact.pack, st["spec"],
-                    artifact.occ, ro_s, rd_s, plan.plan_row, **kw,
-                ))
-            elif tier == "warp":
-                cache.warps += 1
-                colors[slot] = np.asarray(_slot_warp_impl(
-                    artifact.params, artifact.pack, st["spec"],
-                    artifact.occ, ro_s, rd_s, plan.inv_take, plan.take,
-                    plan.valid_cons, **kw,
-                ))
-            else:
-                if cache is not None and key is not None:
-                    cache.misses += 1
-                colors[slot] = self._march_slot(st, artifact, ro_s, rd_s)
-                if (
-                    entry is not None
-                    and entry.uses >= self._pose_grid.build_after
-                ):
-                    from repro.nerf import pose_cache as pc
-
-                    cache.put_plan(key, it.seq, pc.build_warp_plan(
-                        artifact.occ, ro[slot], rd[slot], st["rcfg"],
-                        artifact.cfg, self._pose_grid.margin(artifact.occ),
-                    ))
+            colors[slot] = self._render_slot(st, artifact, it, ro[slot], rd[slot])
         return colors
 
     # ------------------------------------------------------------------
@@ -450,7 +478,10 @@ class ServeEngine:
     ):
         self.cfg = cfg
         self._clock = time.perf_counter if clock is None else clock
-        self._stepper = FusedDeviceStep(cfg) if device_step is None else None
+        self.obs = obs.Recorder()
+        self._stepper = (
+            FusedDeviceStep(cfg, self.obs) if device_step is None else None
+        )
         self._device_step = device_step if device_step is not None else self._stepper
         self._sched = Scheduler(cfg.slots)
         self._events = (
@@ -560,6 +591,10 @@ class ServeEngine:
         `cfg.max_pending` set, a submit that would push the queued-item
         count past the cap raises `AdmissionFull` (counted in the
         `requests_rejected` stat) without enqueuing anything."""
+        with self.obs.span("engine.submit"):
+            return self._submit(rays_o, rays_d, scene, deadline)
+
+    def _submit(self, rays_o, rays_d, scene, deadline) -> int:
         ro = np.asarray(rays_o, np.float32).reshape(-1, 3)
         rd = np.asarray(rays_d, np.float32).reshape(-1, 3)
         assert ro.shape == rd.shape, (ro.shape, rd.shape)
@@ -656,11 +691,49 @@ class ServeEngine:
         internally past fully-expired buckets, so 0 means IDLE — `drain()`
         never stops early on a run of expired work. Returns items removed
         from the queues (rendered + dropped)."""
+        with self.obs.span("engine.step") as sp:
+            with self.obs.span("engine.admit"):
+                dropped_total, scene, entry, items = self._take_live()
+                if not items:
+                    return dropped_total
+                S, R = self.cfg.slots, self.cfg.slot_rays
+                # Padding rays (empty slots / short items) originate far
+                # outside the scene box with zero direction: every sample
+                # is inactive, so padding consumes neither cull budget nor
+                # field compute.
+                ro = np.full((S, R, 3), 10.0, np.float32)
+                rd = np.zeros((S, R, 3), np.float32)
+                for slot, it in enumerate(items):
+                    n = it.stop - it.start
+                    ro[slot, :n] = it.rays_o
+                    rd[slot, :n] = it.rays_d
+            sp.set(scene=scene, items=len(items))
+
+            # The fused stepper's item-aware entry routes each slot through
+            # the pose-cache tiers (hit/warp/march); injected 4-arg fakes
+            # keep the plain padded-bucket protocol.
+            step_items = getattr(self._device_step, "step_items", None)
+            if step_items is not None:
+                colors = np.asarray(step_items(scene, entry.artifact, items, ro, rd))
+            else:
+                colors = np.asarray(self._device_step(scene, entry.artifact, ro, rd))
+            assert colors.shape == (S, R, 3), colors.shape
+            self._steps += 1
+            self._event(
+                ("bucket", scene, tuple((it.rid, it.seq) for it in items))
+            )
+            with self.obs.span("engine.scatter"):
+                self._scatter(items, colors)
+            return dropped_total + len(items)
+
+    def _take_live(self):
+        """(dropped, scene, cache entry, live items) of the next bucket
+        with live work; no live items means idle."""
         dropped_total = 0
         while True:
             scene = self._sched.oldest_scene()
             if scene is None:
-                return dropped_total
+                return dropped_total, None, None, []
             scene2, items = self._sched.take_bucket()
             assert scene2 == scene and items, (scene2, scene)
             now = self._clock()
@@ -681,34 +754,10 @@ class ServeEngine:
             except Exception:
                 self._sched.requeue_front(live)
                 raise
-            items = live
-            break
+            return dropped_total, scene, entry, live
 
-        S, R = self.cfg.slots, self.cfg.slot_rays
-        # Padding rays (empty slots / short items) originate far outside
-        # the scene box with zero direction: every sample is inactive, so
-        # padding consumes neither cull budget nor field compute.
-        ro = np.full((S, R, 3), 10.0, np.float32)
-        rd = np.zeros((S, R, 3), np.float32)
-        for slot, it in enumerate(items):
-            n = it.stop - it.start
-            ro[slot, :n] = it.rays_o
-            rd[slot, :n] = it.rays_d
-
-        # The fused stepper's item-aware entry routes each slot through
-        # the pose-cache tiers (hit/warp/march); injected 4-arg fakes
-        # keep the plain padded-bucket protocol.
-        step_items = getattr(self._device_step, "step_items", None)
-        if step_items is not None:
-            colors = np.asarray(step_items(scene, entry.artifact, items, ro, rd))
-        else:
-            colors = np.asarray(self._device_step(scene, entry.artifact, ro, rd))
-        assert colors.shape == (S, R, 3), colors.shape
-        self._steps += 1
-        self._event(
-            ("bucket", scene, tuple((it.rid, it.seq) for it in items))
-        )
-
+    def _scatter(self, items: List[WorkItem], colors: np.ndarray) -> None:
+        """Rendered colors back into their requests."""
         now = self._clock()
         for slot, it in enumerate(items):
             if self._stepper is not None:
@@ -730,7 +779,6 @@ class ServeEngine:
                     t_submit=req.t_submit, t_done=now,
                 ))
                 self._event(("complete", it.rid))
-        return dropped_total + len(items)
 
     def drain(self) -> None:
         """Process every queue until the engine is idle."""
@@ -835,10 +883,13 @@ class ServeEngine:
             self._stepper.reset_stats()
         if self._events is not None:
             self._events.clear()
+        self.obs.reset()
 
     # ------------------------------------------------------------------
     def stats(self) -> Dict:
-        """Counters, throughput, and ring-based latency percentiles."""
+        """Counters, throughput, ring-based latency percentiles, and the
+        this engine's span and counter aggregates (`trace`, its
+        `repro.obs.Recorder`, since the last `reset_stats()`)."""
         ring = list(self._ring)
         lat_ms = np.asarray(
             [(r.t_done - r.t_submit) * 1e3 for r in ring], np.float64
@@ -900,6 +951,7 @@ class ServeEngine:
                 self._stepper.pose_stats()
                 if self._stepper is not None else None
             ),
+            "trace": self.obs.snapshot(),
         }
 
 

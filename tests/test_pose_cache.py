@@ -322,3 +322,37 @@ def test_engine_fresh_poses_build_nothing(tiny_artifact):
     st = eng.stats()["pose_cache"]
     assert st["builds"] == 0 and st["bytes"] == 0 and st["hits"] == 0
     assert st["cells"] == 4 and st["misses"] == 12
+
+
+def test_engine_tier_counters_and_spans(tiny_artifact):
+    """The engine's spans name each slot's work: two visits march (the
+    second bakes plans), the third hits; only march slots fetch and count
+    their active samples."""
+    scene = tiny_artifact.scene
+    eng = _engine(tiny_artifact)
+    ro, rd = _orbit(0.7, 0.12)
+    eng.reset_stats()
+    for _ in range(3):
+        eng.render(ro, rd, scene=scene)
+    tr = eng.stats()["trace"]
+    c, sp = tr["counters"], tr["spans"]
+    pc = eng.stats()["pose_cache"]
+    assert (pc["misses"], pc["hits"], pc["warps"]) == (6, 3, 0)
+    assert sp["pose.build"]["count"] == 3 and sp["pose.key"]["count"] == 3
+    assert sp["render.slot"]["count"] == 9 and sp["render.wait"]["count"] == 9
+    assert c["render.budget_samples"] == 6 * eng.budget
+    assert c["render.active_samples"] <= c["render.budget_samples"]
+
+
+def test_engine_without_budget_counts_no_samples(tiny_artifact):
+    """With no sample budget the march slot fetches only its colors, and
+    the fill counters, which need a budget, stay empty."""
+    scene = tiny_artifact.scene
+    eng = _engine(tiny_artifact, budget=None, pose_cache=False)
+    ro, rd = _orbit(0.7, 0.12)
+    eng.reset_stats()
+    eng.render(ro, rd, scene=scene)
+    tr = eng.stats()["trace"]
+    assert eng.budget is None
+    assert tr["spans"]["render.wait"]["count"] == 3
+    assert tr["counters"] == {}
