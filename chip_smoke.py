@@ -125,22 +125,6 @@ def print_config(scale) -> None:
           "per iteration")
 
 
-def report_hash_paths(cfg) -> None:
-    from repro.kernels import ops
-    from repro.kernels.hash_encoding_kernel import ONEHOT_MAX_ROWS
-
-    paths = []
-    for lvl in range(cfg.hash.n_levels):
-        rows = cfg.hash.level_entries(lvl)
-        path = ops.hash_level_path(rows)
-        paths.append(path)
-        print(f"[smoke] hash level {lvl:2d}: {rows:7d} rows -> {path}")
-        check(path == ("onehot" if rows <= ONEHOT_MAX_ROWS else "xla_gather"),
-              f"level {lvl} routed by its static table size")
-    check("onehot" in paths and "xla_gather" in paths,
-          "paper widths exercise both hash gather paths")
-
-
 def phase_search(scale):
     import numpy as np
 
@@ -407,7 +391,10 @@ def main(argv=None) -> int:
         else:
             from repro.configs.ngp import paper
 
-            report_hash_paths(paper())
+            h = paper().hash
+            print(f"[smoke] hash: all {h.n_levels} levels "
+                  f"({h.level_entries(0)}..{h.level_entries(h.n_levels - 1)}"
+                  " rows) take one XLA gather")
             with Phase("search", clock, timings):
                 bits = phase_search(scale)
             with Phase("compile", clock, timings):

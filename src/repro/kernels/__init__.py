@@ -3,7 +3,6 @@ each with a pure-jnp oracle (ref.py) and a jit'd wrapper (ops.py).
 
   quant_matmul     — int8 MAC with int32 accumulation (bit-serial numerics)
   alpha_composite  — volume-rendering transmittance walk
-  hash_gather      — hash-level gather as one-hot MXU matmul
   decode_attention — flash-decoding over a long KV cache
   flash_attention  — prefill/train flash attention (scores stay in VMEM)
 """
@@ -11,7 +10,6 @@ from repro.kernels.ops import (
     alpha_composite,
     decode_attention,
     flash_attention,
-    hash_gather,
     quant_matmul,
 )
 
@@ -19,6 +17,5 @@ __all__ = [
     "alpha_composite",
     "decode_attention",
     "flash_attention",
-    "hash_gather",
     "quant_matmul",
 ]

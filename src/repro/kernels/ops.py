@@ -15,7 +15,6 @@ from __future__ import annotations
 
 from collections import Counter
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -28,10 +27,6 @@ from repro.kernels.decode_attention_kernel import (
 )
 from repro.kernels.flash_attention_kernel import (
     flash_attention as _flash_pallas,
-)
-from repro.kernels.hash_encoding_kernel import (
-    ONEHOT_MAX_ROWS,
-    hash_gather as _hash_pallas,
 )
 from repro.kernels.quant_matmul import (
     quant_matmul as _qmm_pallas,
@@ -107,29 +102,15 @@ def quant_matmul_packed(x_codes, wq, sx, sw, zx, use_pallas="auto", **kw):
     )
 
 
-def hash_level_path(rows: int, use_pallas="auto") -> str:
-    """Which gather a hash level of `rows` table rows takes: "onehot" (the
-    Pallas MXU kernel, inside its <= 2^14-row domain) or "xla_gather"
-    (XLA's gather: large levels on any backend, every level on the
-    reference path). A static rule on table sizes, shared by the
-    dispatch below and by anything that reports the routing."""
-    run, _ = _resolve(use_pallas, "hash_gather")
-    return "onehot" if run and rows <= ONEHOT_MAX_ROWS else "xla_gather"
-
-
-def hash_encode(corner_idx, corner_w, table_cat, level_rows,
-                use_pallas="auto", **kw):
-    """Fused multi-level hash-grid encode: gathers over a concatenated
-    table + trilinear interpolation.
+def hash_encode(corner_idx, corner_w, table_cat, level_rows):
+    """Fused multi-level hash-grid encode: ONE XLA gather over the
+    concatenated table for all levels + trilinear interpolation.
 
     corner_idx    (L, B, 8) int32 — per-level in-table corner indices
     corner_w      (L, B, 8) f32   — matching trilinear weights
     table_cat     (T, F)    f32   — all level tables stacked row-wise
     level_rows    L static ints   — each level's row count in table_cat
 
-    Each level inside the one-hot kernel's domain gathers from its own
-    static slice through `hash_gather`; every larger level goes through
-    ONE XLA gather over the concatenated table (`hash_level_path`).
     Returns (B, L*F) features in level-major column order — bit-identical
     to gathering each level's table separately and concatenating (pinned
     by tests).
@@ -140,29 +121,16 @@ def hash_encode(corner_idx, corner_w, table_cat, level_rows,
         raise ValueError(f"level_rows {rows} do not split a "
                          f"{table_cat.shape[0]}-row table into {L} levels")
     offs = np.concatenate([[0], np.cumsum(rows)[:-1]]).astype(np.int32)
-    vals, big = [None] * L, []
-    for l, (off, r) in enumerate(zip(offs.tolist(), rows)):
-        if hash_level_path(r, use_pallas) == "onehot":
-            tab = jax.lax.slice_in_dim(table_cat, off, off + r)
-            vals[l] = hash_gather(corner_idx[l].reshape(-1), tab,
-                                  use_pallas=use_pallas, **kw)
-        else:
-            big.append(l)
-    if big:
-        flat = (corner_idx[np.asarray(big)]
-                + jnp.asarray(offs[big])[:, None, None]).reshape(-1)
-        got = ref.hash_gather_ref(flat, table_cat).reshape(len(big), B * C, -1)
-        for i, l in enumerate(big):
-            vals[l] = got[i]
-    vals = jnp.stack(vals).reshape(L, B, C, -1)
+    flat = (corner_idx + jnp.asarray(offs)[:, None, None]).reshape(-1)
+    vals = hash_gather(flat, table_cat).reshape(L, B, C, -1)
     feats = jnp.sum(vals * corner_w[..., None], axis=2)  # (L, B, F)
     return jnp.moveaxis(feats, 0, 1).reshape(B, -1)
 
 
 def fused_field_query(corner_idx, corner_w, table_cat, level_rows,
                       wq, act, use_pallas="auto", **kw):
-    """hash_gather -> trilinear interp -> quantized matmul, the fused
-    first-layer field query of `FastRenderEngine`'s integer path.
+    """hash_encode -> quantized matmul, the fused first-layer field query
+    of `FastRenderEngine`'s integer path.
 
     `act` carries the activation grid of the first linear layer (the
     FusedPack layer dict fields): sx scale, zx int zero point (int8-
@@ -170,8 +138,7 @@ def fused_field_query(corner_idx, corner_w, table_cat, level_rows,
     `wq` is the layer's `PackedTensor` (planar or tile-native). Returns
     the f32 pre-activation (B, N).
     """
-    enc = hash_encode(corner_idx, corner_w, table_cat, level_rows,
-                      use_pallas=use_pallas)
+    enc = hash_encode(corner_idx, corner_w, table_cat, level_rows)
     codes = jnp.clip(jnp.round(enc / act["sx"] + act["zx_f"]), 0.0,
                      act["qmax"])
     ci8 = (codes - act["off"]).astype(jnp.int8)
@@ -211,12 +178,10 @@ def ray_march(occ, rays_o, rays_d, t, use_pallas="auto", **kw):
     )
 
 
-def hash_gather(indices, table, use_pallas="auto", **kw):
-    """table[indices]: the one-hot kernel inside its domain, XLA's gather
-    for larger tables and on the reference path (`hash_level_path`)."""
-    if hash_level_path(table.shape[0], use_pallas) == "xla_gather":
-        return ref.hash_gather_ref(indices, table)
-    return _hash_pallas(indices, table, interpret=not _on_tpu(), **kw)
+def hash_gather(indices, table):
+    """table[indices] through XLA's gather, on every backend (exact: the
+    rows come back bit for bit)."""
+    return ref.hash_gather_ref(indices, table)
 
 
 def decode_attention(q, k, v, length, use_pallas="auto", **kw):

@@ -13,8 +13,8 @@ the box, so skipping never changes the result, unlike the composite
 kernel's t_eps tolerance).
 
 The per-sample occupancy lookup is a gather with data-dependent indices;
-TPUs have no per-lane random gather, so (hash_encoding_kernel's trick)
-it is re-expressed as one-hot MXU matmuls: the (G, G, G) grid is viewed
+TPUs have no per-lane random gather inside a kernel, so it is
+re-expressed as one-hot MXU matmuls: the (G, G, G) grid is viewed
 as a (G, G*G) matrix whose column x*G + y is the z-column of cell (x, y),
 each ray's (1, bs) sample row selects its columns with a (bt, bs) one-hot
 per table chunk of `bt` columns (so the one-hot never exceeds (bt, bs) in

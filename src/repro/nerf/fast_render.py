@@ -21,9 +21,9 @@ batched env's PSNR proxy — rebuilt around three ideas:
    codes on the fly and the five NGP linears lower through
    `kernels.ops.quant_matmul_packed` (packed words expanded to int8 codes
    inside the kernel + int32 MXU accumulation), the hash lookups through
-   `kernels.ops.hash_gather` over the dequantized codes. On backends
-   without an int8 matmul unit (CPU), the same codes run on a float
-   carrier — identical quantization grid, f32 accumulation — because
+   XLA's gather (`kernels.ops.hash_encode`) over the dequantized codes.
+   On backends without an int8 matmul unit (CPU), the same codes run on
+   a float carrier — identical quantization grid, f32 accumulation — because
    XLA's int32 dot is ~2.5x slower than f32 there; `use_pallas=True`
    forces the integer kernels everywhere (the parity tests do).
    `mode="reference"` keeps fake-quant `ngp_apply` as the oracle inside
@@ -379,7 +379,7 @@ def fused_ngp_apply(
                 use_pallas=use_pallas,
             ) + lyr["b"]
         else:
-            enc = ops_hash_encode(idx, w, cat, rows, use_pallas=use_pallas)
+            enc = ops_hash_encode(idx, w, cat, rows)
             h = _fused_linear(pack, 0, names[0], enc, use_pallas)
     else:
         # Storage-only pack (schema-v2 artifact loaded without repack):
@@ -396,9 +396,8 @@ def fused_ngp_apply(
                 # runs over the dequantized grid (codes * scale), expanded
                 # inside the jitted call — DRAM holds the packed bytes.
                 table = table.dequantize()
-            vals = ops_hash_gather(
-                idx.reshape(-1), table, use_pallas=use_pallas
-            ).reshape(idx.shape + (cfg.hash.n_features,))
+            vals = ops_hash_gather(idx.reshape(-1), table).reshape(
+                idx.shape + (cfg.hash.n_features,))
             feats.append(jnp.sum(vals * w[..., None], axis=1))
         enc = jnp.concatenate(feats, axis=-1)
         h = _fused_linear(pack, 0, names[0], enc, use_pallas)

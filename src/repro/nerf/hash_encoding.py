@@ -10,10 +10,8 @@ with pi = (1, 2654435761, 805459861), computed in uint32 (wrap-around is the
 spec). Per-level quantization (the paper's contribution) fake-quantizes each
 level's table independently with its assigned bit width.
 
-TPU note (see DESIGN.md §3): the gather here is XLA `take`; the Pallas kernel
-in repro/kernels/hash_encoding re-expresses the gather as a one-hot MXU
-matmul for VMEM-resident levels and is numerically checked against this
-module (ref oracle).
+TPU note: the gather here is XLA `take`, as is the serve path's
+`repro.kernels.ops.hash_encode` (one gather over all levels' tables).
 """
 from __future__ import annotations
 
@@ -117,8 +115,8 @@ def level_corner_data(
     """Per-level voxel-corner indices and trilinear weights.
 
     points: (P, 3) in [0, 1].  Returns (idx (P, 8) int32, w (P, 8) f32).
-    Shared by the XLA path and the Pallas kernel wrapper (which consumes the
-    indices and does the gather+lerp on-chip).
+    Shared by `hash_encode` below and the serve path's
+    `repro.kernels.ops.hash_encode`.
     """
     res = cfg.resolutions()[level]
     x = points * res

@@ -161,7 +161,7 @@ def _hash_inputs(L=3, B=37, T=64, F=2, seed=3):
 
 def test_hash_encode_matches_per_level_gather():
     idx, w, tables, cat, rows = _hash_inputs()
-    got = ops.hash_encode(idx, w, cat, rows, use_pallas=False)
+    got = ops.hash_encode(idx, w, cat, rows)
     per_level = [
         jnp.sum(tables[l][idx[l]] * w[l][..., None], axis=1)
         for l in range(len(tables))
@@ -179,7 +179,7 @@ def test_fused_field_query_matches_manual_pipeline():
            "zx": jnp.int32(0)}
     got = ops.fused_field_query(idx, w, cat, rows, wt, act, use_pallas=True)
 
-    enc = ops.hash_encode(idx, w, cat, rows, use_pallas=False)
+    enc = ops.hash_encode(idx, w, cat, rows)
     codes = jnp.clip(jnp.round(enc / act["sx"] + act["zx_f"]), 0.0,
                      act["qmax"])
     ci8 = (codes - act["off"]).astype(jnp.int8)
@@ -189,53 +189,116 @@ def test_fused_field_query_matches_manual_pipeline():
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("use_pallas", [True, False])
-def test_hash_encode_routing_across_onehot_domain(use_pallas):
-    """Levels on both sides of the one-hot kernel's 2^14-row domain: small
-    levels take the kernel, larger ones XLA's gather, and the encode is
-    bit-identical to per-level `jnp.take` either way."""
-    from repro.kernels.hash_encoding_kernel import ONEHOT_MAX_ROWS
+def _per_level_take(tables, idx, w):
+    return jnp.concatenate([
+        jnp.sum(jnp.take(tables[l], idx[l], axis=0) * w[l][..., None],
+                axis=1)
+        for l in range(len(tables))
+    ], axis=-1)
 
-    rows = (300, ONEHOT_MAX_ROWS, ONEHOT_MAX_ROWS + 1, 3 * ONEHOT_MAX_ROWS)
-    rng = np.random.RandomState(5)
-    B = 16
-    tables = [jnp.asarray(rng.randn(r, 2), jnp.float32) for r in rows]
+
+def _level_inputs(rows, F, B, seed):
+    """Per-level tables of `rows` rows and corner data whose first two
+    corners pin each level's first and last row."""
+    rng = np.random.RandomState(seed)
+    tables = [jnp.asarray(rng.standard_normal((r, F)), jnp.float32)
+              for r in rows]
     idx = jnp.asarray(np.stack([rng.randint(0, r, size=(B, 8)) for r in rows]),
                       jnp.int32)
-    # Pin the row extremes of every level: first, last, and (for the
-    # large levels) the boundary rows around 2^14.
     idx = idx.at[:, 0, 0].set(0)
     idx = idx.at[:, 0, 1].set(jnp.asarray([r - 1 for r in rows]))
     w = jnp.asarray(rng.dirichlet(np.ones(8), size=(len(rows), B)),
                     jnp.float32)
-    paths = [ops.hash_level_path(r, use_pallas) for r in rows]
-    if use_pallas:
-        assert paths == ["onehot", "onehot", "xla_gather", "xla_gather"]
-    else:
-        assert set(paths) == {"xla_gather"}
-    got = ops.hash_encode(idx, w, jnp.concatenate(tables), rows,
-                          use_pallas=use_pallas)
-    want = jnp.concatenate([
-        jnp.sum(jnp.take(tables[l], idx[l], axis=0) * w[l][..., None],
-                axis=1)
-        for l in range(len(rows))
-    ], axis=-1)
+    return tables, idx, w
+
+
+@pytest.mark.parametrize("use_pallas", [True, False])
+def test_hash_encode_routing_across_onehot_domain(use_pallas):
+    """Levels on both sides of 2^14 rows (the bound of the one-hot kernel
+    this gather replaced): the encode is bit-identical to per-level
+    `jnp.take`, and so is the field query's first layer built on it, on
+    the kernel path and on the reference path."""
+    rows = (300, 2 ** 14, 2 ** 14 + 1, 3 * 2 ** 14)
+    tables, idx, w = _level_inputs(rows, F=2, B=16, seed=5)
+    cat = jnp.concatenate(tables)
+    want = _per_level_take(tables, idx, w)
+    got = ops.hash_encode(idx, w, cat, rows)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
+    wq = repack_tile_native(_packed(2 * len(rows), 16, 4, scale=0.03))
+    act = {"sx": 0.05, "zx_f": 128.0, "qmax": 255.0, "off": 128,
+           "zx": jnp.int32(0)}
+    codes = jnp.clip(jnp.round(want / act["sx"] + act["zx_f"]), 0.0,
+                     act["qmax"])
+    ci8 = (codes - act["off"]).astype(jnp.int8)
+    want_h = ops.quant_matmul_packed(ci8, wq, act["sx"], wq.scale, act["zx"],
+                                     use_pallas=use_pallas)
+    got_h = ops.fused_field_query(idx, w, cat, rows, wq, act,
+                                  use_pallas=use_pallas)
+    np.testing.assert_array_equal(np.asarray(got_h), np.asarray(want_h))
 
-def test_raw_hash_gather_refuses_out_of_domain_table():
-    from repro.kernels.hash_encoding_kernel import (
-        ONEHOT_MAX_ROWS,
-        hash_gather,
-    )
 
-    idx = jnp.zeros((8,), jnp.int32)
-    with pytest.raises(ValueError, match="domain"):
-        hash_gather(idx, jnp.zeros((ONEHOT_MAX_ROWS + 1, 2)), interpret=True)
-    # ...while the canonical entry routes the same table to XLA's gather.
-    got = ops.hash_gather(idx, jnp.ones((ONEHOT_MAX_ROWS + 1, 2)),
-                          use_pallas=True)
-    np.testing.assert_array_equal(np.asarray(got), np.ones((8, 2)))
+def _bench_level_rows(name):
+    import json
+    from pathlib import Path
+
+    from repro.nerf.hash_encoding import HashEncodingConfig
+
+    path = Path(__file__).resolve().parents[1] / "bench" / "configs" / \
+        f"{name}.json"
+    m = json.loads(path.read_text())["model"]
+    cfg = HashEncodingConfig(**{k: m[k] for k in (
+        "n_levels", "n_features", "log2_table_size", "base_resolution",
+        "max_resolution")})
+    return tuple(cfg.level_entries(l) for l in range(cfg.n_levels))
+
+
+@pytest.mark.parametrize("F", [2, 4])
+@pytest.mark.parametrize("config", ["ngp-paper-t19", "ngp-paper-t14"])
+def test_hash_encode_bit_identical_at_bench_level_sizes(config, F):
+    """All 16 levels of each benchmark configuration (4,913 rows up to
+    the full table) through the one gather over the concatenated table."""
+    rows = _bench_level_rows(config)
+    assert len(rows) == 16 and min(rows) == 17 ** 3
+    tables, idx, w = _level_inputs(rows, F=F, B=24, seed=F)
+    got = ops.hash_encode(idx, w, jnp.concatenate(tables), rows)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(_per_level_take(tables, idx, w)))
+
+
+def _eqns(jaxpr):
+    """Every equation of `jaxpr` and its sub-jaxprs, not descending into
+    Pallas kernel bodies."""
+    for e in jaxpr.eqns:
+        yield e
+        if e.primitive.name == "pallas_call":
+            continue
+        for v in e.params.values():
+            for x in v if isinstance(v, (tuple, list)) else (v,):
+                if hasattr(x, "eqns"):
+                    yield from _eqns(x)
+                elif hasattr(x, "jaxpr") and hasattr(x.jaxpr, "eqns"):
+                    yield from _eqns(x.jaxpr)
+
+
+@pytest.mark.parametrize("use_pallas", [True, False])
+def test_fused_field_query_is_one_gather_and_the_quant_kernel(use_pallas):
+    """The hash lookup is ONE gather over the concatenated table, and the
+    only Pallas kernel in the field query is the quantized matmul's."""
+    idx, w, _, cat, rows = _hash_inputs(L=4, B=29, T=32, F=2)
+    wq = repack_tile_native(_packed(8, 16, 4, scale=0.03))
+    act = {"sx": 0.05, "zx_f": 128.0, "qmax": 255.0, "off": 128,
+           "zx": jnp.int32(0)}
+    jaxpr = jax.make_jaxpr(
+        lambda i, w_, c: ops.fused_field_query(i, w_, c, rows, wq, act,
+                                               use_pallas=use_pallas)
+    )(idx, w, cat).jaxpr
+    eqns = list(_eqns(jaxpr))
+    gathers = [e for e in eqns if e.primitive.name == "gather"]
+    assert [e.invars[0].aval.shape for e in gathers] == [cat.shape]
+    kernels = [e.params["jaxpr"].debug_info.func_name for e in eqns
+               if e.primitive.name == "pallas_call"]
+    assert kernels == (["_qmm_packed_kernel"] if use_pallas else [])
 
 
 # ---------------------------------------------------------------------------

@@ -7,7 +7,6 @@ import pytest
 from repro.kernels import ref
 from repro.kernels.alpha_composite import alpha_composite
 from repro.kernels.decode_attention_kernel import decode_attention
-from repro.kernels.hash_encoding_kernel import hash_gather
 from repro.kernels.quant_matmul import quant_matmul
 
 
@@ -60,18 +59,6 @@ def test_alpha_composite_opaque_wall():
     c, a = alpha_composite(sigma, rgb, delta, br=4, bs=8)
     np.testing.assert_allclose(np.asarray(a), 1.0, atol=1e-5)
     np.testing.assert_allclose(np.asarray(c), 0.0, atol=1e-5)  # rgb_0 = 0
-
-
-@pytest.mark.parametrize("p,t,f", [(10, 100, 2), (333, 1000, 2), (256, 512, 4),
-                                   (77, 4096, 8)])
-def test_hash_gather(p, t, f):
-    key = jax.random.PRNGKey(p + t)
-    k1, k2 = jax.random.split(key)
-    table = jax.random.normal(k1, (t, f))
-    idx = jax.random.randint(k2, (p,), 0, t)
-    got = hash_gather(idx, table, bp=64, bt=256)
-    want = ref.hash_gather_ref(idx, table)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
 
 
 @pytest.mark.parametrize("b,hkv,g,s,hd", [(1, 1, 1, 32, 16), (2, 4, 3, 100, 16),

@@ -1,5 +1,6 @@
-"""Compile rehearsal: the serve path's Pallas kernels at the paper's
-Instant-NGP widths, for a described (not attached) TPU v5e.
+"""Compile rehearsal: the serve path's Pallas kernels (and the hash
+encode's XLA gather) at the paper's Instant-NGP widths, for a described
+(not attached) TPU v5e.
 
 Interpret mode cannot see what the chip's compiler refuses: blocks not
 aligned to the (8, 128) tiling, operand types the MXU does not take,
@@ -21,7 +22,7 @@ import pytest
 from repro.configs.ngp import paper
 from repro.kernels.alpha_composite import alpha_composite
 from repro.kernels.autotune import RAY_MARCH_DEFAULT
-from repro.kernels.hash_encoding_kernel import ONEHOT_MAX_ROWS, hash_gather
+from repro.kernels.ops import hash_encode
 from repro.kernels.quant_matmul import quant_matmul_packed
 from repro.kernels.ray_march import ray_march
 from repro.nerf.ngp import _linear_dims
@@ -85,18 +86,19 @@ def test_quant_matmul_packed_compiles_at_paper_mlp_shapes(spec, bits, layout):
     assert text.count("tpu_custom_call") >= len(shapes)
 
 
-def test_hash_gather_compiles_at_largest_in_domain_level(spec):
+def test_hash_encode_compiles_to_xla_gather_at_paper_widths(spec):
+    """All 16 levels of the T=2^19 field, 4,096 points: one XLA gather
+    over the concatenated table and no Mosaic kernel."""
     cfg = paper().hash
-    rows = max(
-        cfg.level_entries(l) for l in range(cfg.n_levels)
-        if cfg.level_entries(l) <= ONEHOT_MAX_ROWS
-    )
+    L = cfg.n_levels
+    rows = tuple(cfg.level_entries(l) for l in range(L))
     text = _compiled_text(
-        lambda i, t: hash_gather(i, t, interpret=False),
-        spec((POINTS * 8,), jnp.int32),
-        spec((rows, cfg.n_features), jnp.float32),
+        lambda i, w, t: hash_encode(i, w, t, rows),
+        spec((L, POINTS, 8), jnp.int32), spec((L, POINTS, 8), jnp.float32),
+        spec((sum(rows), cfg.n_features), jnp.float32),
     )
-    assert "tpu_custom_call" in text
+    assert " gather(" in text
+    assert "tpu_custom_call" not in text
 
 
 def test_ray_march_compiles_at_g32(spec):
