@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Smoke run of HERO's main path on a TPU, at the paper's Instant-NGP
 widths (16 hash levels, F=2, T=2^19, resolutions 16..2048, 64-wide MLPs,
-SH degree 4), through the public `repro.hero` API.
+16 SH coefficients), through the public `repro.hero` API.
 
     python chip_smoke.py             # one chip: search -> compile -> serve
     python chip_smoke.py --chips 4   # four chips: sharded population scoring

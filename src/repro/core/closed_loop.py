@@ -120,14 +120,15 @@ class SceneScale:
     def paper() -> "SceneScale":
         """The paper's field at its published widths
         (`configs/ngp.py:paper()`: 16 levels, F=2, T=2^19, resolutions
-        16..2048, 64-wide MLPs, SH degree 4). Only depth is cut from the
+        16..2048, 64-wide MLPs, 16 SH coefficients: `sh_degree` 3, bands
+        0-3, Instant-NGP Sec. 5.4). Only depth is cut from the
         Blender-synthetic setup (800x800 frames, 100 train / 200 test
         views): see `PAPER_DEPTH_CUTS`."""
         return SceneScale(
             image_hw=64, n_train_views=8, n_test_views=2, n_levels=16,
             log2_table=19, max_res=2048, hidden=64, n_samples=32,
             train_steps=300, finetune_steps=8, trace_rays=256,
-            proxy_rays=512, base_res=16, sh_degree=4,
+            proxy_rays=512, base_res=16, sh_degree=3,
         )
 
     @staticmethod

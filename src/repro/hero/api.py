@@ -121,7 +121,10 @@ def serve(
     """Stand up the batched fused render serving layer.
 
     One `QuantArtifact` -> the single-artifact `RenderService` facade
-    (PR-4 surface). A dict/list of artifacts -> the multi-scene
+    (PR-4 surface). An Instant-NGP artifact serves through its occupancy
+    grid, sample budget and pose-cache tiers; a Nerfacto artifact
+    (`artifact.proposal_sampled`) through its proposal and shading
+    programs at fixed samples a ray. A dict/list of artifacts -> the multi-scene
     `ServeEngine` (continuous batching across scenes, LRU artifact cache
     with `loader` on miss and `cache_bytes` eviction budget, streaming
     `poll()`). `cfg` is a `ServeConfig` (shared knobs) or, for the

@@ -13,7 +13,10 @@ render service needs to serve the scene without the training stack:
     hash-table code words (`repro.quant.packing.PackedTensor` bit-plane
     layout) + scales (loaded verbatim, not rebuilt: the bundle IS the
     deploy format, and a 4-bit policy ships 4-bit payloads);
-  - the baked occupancy grid (empty-space culling at serve time);
+  - the baked occupancy grid (empty-space culling at serve time), for
+    an Instant-NGP field; a Nerfacto field (`NerfactoConfig`) has none:
+    its proposal fields place the samples, and its pack is a
+    `NerfactoPack` of three packed fields;
   - hardware-target metadata + latency/model-size/PSNR at compile, with
     `model_bytes` MEASURED from the stored payload bytes — by the shared
     size function, exactly the frontier's model_bytes for the policy.
@@ -38,15 +41,17 @@ import hashlib
 import json
 import os
 from pathlib import Path
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.repack import DEFAULT_TILE_BK, unrepack_planar
+from repro.nerf import nerfacto
 from repro.nerf.fast_render import (
     FastRenderEngine,
     FusedPack,
+    NerfactoPack,
     build_fused_pack,
     fused_pack_stored_bytes,
     repack_fused_pack,
@@ -75,27 +80,37 @@ class QuantArtifact:
 
     scene: str
     bits: List[int]
-    cfg: NGPConfig
-    rcfg: RenderConfig
+    cfg: Union[NGPConfig, nerfacto.NerfactoConfig]
+    rcfg: Optional[RenderConfig]  # None for Nerfacto (the config samples)
     # Full SceneConfig (as a dict) of the dataset the compile metrics were
     # measured on — a consumer can rebuild the EXACT eval set (parity
     # comparisons against `metrics["psnr"]` are meaningless on any other).
     scene_cfg: Dict
     params: Dict  # finetuned float weights, {top: {sub: array}}
     act_ranges: jnp.ndarray  # (n_linear, 2) calibrated activation ranges
-    pack: FusedPack  # packed integer inference form
-    occ: OccupancyGrid
+    pack: Union[FusedPack, NerfactoPack]  # packed integer inference form
+    occ: Optional[OccupancyGrid]  # None for Nerfacto
     hardware: Dict  # HardwareTarget.describe() of the search target
     metrics: Dict  # psnr / latency_cycles / model_bytes / fqr at compile
     schema_version: int = SCHEMA_VERSION
 
     # ------------------------------------------------------------------
-    def spec(self) -> NGPQuantSpec:
+    @property
+    def proposal_sampled(self) -> bool:
+        """A Nerfacto field: fixed samples a ray placed by its proposal
+        fields, no occupancy grid, no sample budget."""
+        return isinstance(self.cfg, nerfacto.NerfactoConfig)
+
+    def spec(self):
         """Quant spec re-derived from (bits, act_ranges) — identical to the
         one the compile step used (same `spec_from_policy` path)."""
-        units = make_quant_units(self.cfg)
-        policy = QuantPolicy.uniform(units, 8).with_bits(list(self.bits))
-        return spec_from_policy(self.cfg, policy, self.act_ranges)
+        if self.proposal_sampled:
+            units, to_spec = nerfacto.make_quant_units, nerfacto.spec_from_policy
+        else:
+            units, to_spec = make_quant_units, spec_from_policy
+        policy = QuantPolicy.uniform(units(self.cfg), 8).with_bits(
+            list(self.bits))
+        return to_spec(self.cfg, policy, self.act_ranges)
 
     def engine(self, **kw) -> FastRenderEngine:
         """Fused render engine over the LOADED pack (codes are served
@@ -111,7 +126,7 @@ class QuantArtifact:
         (packed weight/table words + any f32 carriers) — the number
         `metrics["model_bytes"]` records and the frontier's shared size
         function predicts."""
-        return fused_pack_stored_bytes(self.pack)
+        return sum(fused_pack_stored_bytes(p) for _, p in self.pack.fields())
 
     def resident_bytes(self) -> int:
         """Total in-memory bytes of everything the artifact keeps resident
@@ -125,16 +140,21 @@ class QuantArtifact:
                 return int(v.words.nbytes + v.scale.nbytes + v.offset.nbytes)
             return int(v.nbytes)
 
-        total = nb(self.act_ranges) + nb(self.occ.occ)
+        total = nb(self.act_ranges)
+        if self.occ is not None:
+            total += nb(self.occ.occ)
+        if self.proposal_sampled:
+            total += nb(self.pack.appearance)
         for sub in self.params.values():
             total += sum(nb(v) for v in sub.values())
-        for lyr in self.pack.layers.values():
-            total += sum(nb(v) for v in lyr.values())
-        total += sum(nb(t) for t in self.pack.hash_tables.values())
-        # Staged compute-layout forms (tile-native words, concatenated
-        # dequantized tables, f32 carriers) are resident too — the cache
-        # charges for the speed, even though stored bytes don't change.
-        total += sum(nb(v) for v in self.pack.compute.values())
+        for _, pack in self.pack.fields():
+            for lyr in pack.layers.values():
+                total += sum(nb(v) for v in lyr.values())
+            total += sum(nb(t) for t in pack.hash_tables.values())
+            # Staged compute-layout forms (tile-native words, concatenated
+            # dequantized tables, f32 carriers) are resident too — the
+            # cache charges for the speed; stored bytes don't change.
+            total += sum(nb(v) for v in pack.compute.values())
         return total
 
     def cache_key(self) -> str:
@@ -155,7 +175,9 @@ class QuantArtifact:
 
         A `PackedTensor` value at logical key K becomes three arrays
         (K::pt::words / K::pt::scale / K::pt::offset); its static (bits,
-        shape) ride in the manifest's `packed_tensors[K]`."""
+        shape) ride in the manifest's `packed_tensors[K]`. A proposal
+        field's layers and tables carry its name after the `pack` /
+        `packtab` root (`pack::prop1::prop1/0::wq`)."""
         out: Dict[str, np.ndarray] = {"act_ranges": np.asarray(self.act_ranges)}
         packed: Dict[str, Dict] = {}
 
@@ -180,12 +202,17 @@ class QuantArtifact:
         for top, sub in self.params.items():
             for k, v in sub.items():
                 out[f"params{_SEP}{top}{_SEP}{k}"] = np.asarray(v)
-        for name, lyr in self.pack.layers.items():
-            for k, v in lyr.items():
-                emit(f"pack{_SEP}{name}{_SEP}{k}", v)
-        for name, t in self.pack.hash_tables.items():
-            emit(f"packtab{_SEP}{name}", t)
-        out["occ"] = np.asarray(self.occ.occ)
+        for field, pack in self.pack.fields():
+            root = f"{_SEP}{field}" if field else ""
+            for name, lyr in pack.layers.items():
+                for k, v in lyr.items():
+                    emit(f"pack{root}{_SEP}{name}{_SEP}{k}", v)
+            for name, t in pack.hash_tables.items():
+                emit(f"packtab{root}{_SEP}{name}", t)
+        if self.proposal_sampled:
+            out["appearance"] = np.asarray(self.pack.appearance)
+        if self.occ is not None:
+            out["occ"] = np.asarray(self.occ.occ)
         return out, packed
 
     def save(self, path) -> Path:
@@ -196,14 +223,19 @@ class QuantArtifact:
         arrays, packed_meta = self._arrays()
         manifest = {
             "schema_version": SCHEMA_VERSION,
+            "model": "nerfacto" if self.proposal_sampled else "ngp",
             "packed_tensors": packed_meta,
             "scene": self.scene,
             "bits": [int(b) for b in self.bits],
             "cfg": dataclasses.asdict(self.cfg),
-            "rcfg": dataclasses.asdict(self.rcfg),
+            "rcfg": (None if self.rcfg is None
+                     else dataclasses.asdict(self.rcfg)),
             "scene_cfg": self.scene_cfg,
-            "pack_modes": list(self.pack.modes),
-            "occ": {
+            "pack_modes": (
+                {f: list(p.modes) for f, p in self.pack.fields()}
+                if self.proposal_sampled else list(self.pack.modes)
+            ),
+            "occ": None if self.occ is None else {
                 "resolution": self.occ.resolution,
                 "threshold": self.occ.threshold,
                 "occupied_fraction": self.occ.occupied_fraction,
@@ -263,9 +295,10 @@ class QuantArtifact:
                 raise ValueError(f"artifact {path}: array {k!r} failed its "
                                  "sha256 integrity check")
 
-        cfg_d = dict(manifest["cfg"])
-        cfg = NGPConfig(hash=HashEncodingConfig(**cfg_d.pop("hash")), **cfg_d)
-        rcfg = RenderConfig(**manifest["rcfg"])
+        model = manifest.get("model", "ngp")
+        cfg = _config_from_dict(model, manifest["cfg"])
+        rcfg = (None if manifest["rcfg"] is None
+                else RenderConfig(**manifest["rcfg"]))
 
         packed_meta = manifest.get("packed_tensors", {})
 
@@ -281,27 +314,36 @@ class QuantArtifact:
             )
 
         params: Dict[str, Dict] = {}
-        layers: Dict[str, Dict] = {}
-        tables: Dict[str, jnp.ndarray] = {}
+        # field name ("" = the main field) -> its layers / tables
+        layers: Dict[str, Dict[str, Dict]] = {}
+        tables: Dict[str, Dict[str, jnp.ndarray]] = {}
+
+        def place(parts, value):
+            # pack[::field]::layer::key and packtab[::field]::table
+            rest = parts[1:]
+            depth = 2 if parts[0] == "pack" else 1
+            field = rest.pop(0) if len(rest) > depth else ""
+            if parts[0] == "pack":
+                layers.setdefault(field, {}).setdefault(rest[0], {})[
+                    rest[1]] = value
+            else:
+                tables.setdefault(field, {})[rest[0]] = value
+
         for k, v in arrays.items():
             parts = k.split(_SEP)
             if len(parts) >= 2 and parts[-2] == "pt":
                 continue  # component of a PackedTensor, handled below
             if parts[0] == "params":
                 params.setdefault(parts[1], {})[parts[2]] = jnp.asarray(v)
-            elif parts[0] == "pack":
-                layers.setdefault(parts[1], {})[parts[2]] = jnp.asarray(v)
-            elif parts[0] == "packtab":
-                tables[parts[1]] = jnp.asarray(v)
+            elif parts[0] in ("pack", "packtab"):
+                place(parts, jnp.asarray(v))
         for prefix in packed_meta:
             parts = prefix.split(_SEP)
-            if parts[0] == "pack":
-                layers.setdefault(parts[1], {})[parts[2]] = take_packed(prefix)
-            elif parts[0] == "packtab":
-                tables[parts[1]] = take_packed(prefix)
+            if parts[0] in ("pack", "packtab"):
+                place(parts, take_packed(prefix))
 
         occ_meta = manifest["occ"]
-        occ = OccupancyGrid(
+        occ = None if occ_meta is None else OccupancyGrid(
             occ=jnp.asarray(arrays["occ"]),
             resolution=int(occ_meta["resolution"]),
             threshold=float(occ_meta["threshold"]),
@@ -324,12 +366,25 @@ class QuantArtifact:
             pack = build_fused_pack(params, cfg, spec, layout=layout)
             metrics["model_bytes"] = float(fused_pack_stored_bytes(pack))
         else:
-            pack = FusedPack(
-                layers=layers, hash_tables=tables,
-                modes=tuple(manifest["pack_modes"]),
-            )
-            if layout != "planar":
-                pack = repack_fused_pack(pack, layout)
+            modes = manifest["pack_modes"]
+            if model != "nerfacto":
+                modes = {"": modes}
+            fields = {}
+            for field, m in modes.items():
+                fields[field] = FusedPack(
+                    layers=layers[field], hash_tables=tables[field],
+                    modes=tuple(m),
+                )
+                if layout != "planar":
+                    fields[field] = repack_fused_pack(fields[field], layout)
+            pack = fields.pop("")
+            if model == "nerfacto":
+                pack = NerfactoPack(
+                    main=pack,
+                    proposals=tuple(fields[f"prop{k + 1}"]
+                                    for k in range(len(fields))),
+                    appearance=jnp.asarray(arrays["appearance"]),
+                )
 
         return QuantArtifact(
             scene=manifest["scene"],
@@ -345,6 +400,25 @@ class QuantArtifact:
             metrics=metrics,
             schema_version=SCHEMA_VERSION,
         )
+
+
+def _ngp_config(d: Dict) -> NGPConfig:
+    d = dict(d)
+    return NGPConfig(hash=HashEncodingConfig(**d.pop("hash")), **d)
+
+
+def _config_from_dict(model: str, d: Dict):
+    """The manifest's `cfg` back into the model's frozen config."""
+    if model == "ngp":
+        return _ngp_config(d)
+    if model != "nerfacto":
+        raise ValueError(f"unknown model {model!r} in artifact manifest")
+    d = dict(d)
+    return nerfacto.NerfactoConfig(
+        field=_ngp_config(d.pop("field")),
+        proposals=tuple(HashEncodingConfig(**h) for h in d.pop("proposals")),
+        n_resampled=tuple(d.pop("n_resampled")), **d,
+    )
 
 
 def _sha(a: np.ndarray) -> str:

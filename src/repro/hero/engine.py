@@ -25,8 +25,9 @@ renders, and they are the design constraint on this layer:
     come from it, so a fake counter makes timing assertions exact.
   * `device_step=` — `(scene, artifact, ro, rd) -> (S, R, 3) colors`;
     defaults to `FusedDeviceStep` (the real fused integer render with
-    grow-on-overflow sample budgets). A scripted fake turns `step()`
-    into a pure state transition.
+    grow-on-overflow sample budgets, or for a proposal-sampled Nerfacto
+    artifact its two programs a slot at fixed samples). A scripted fake
+    turns `step()` into a pure state transition.
 
 `loader=` (scene -> artifact) serves cache misses; `size_fn=` prices an
 artifact for the byte budget (defaults to `resident_bytes()` where
@@ -112,6 +113,11 @@ class ArtifactCache:
     def __contains__(self, scene: str) -> bool:
         return scene in self._entries
 
+    def peek(self, scene: str):
+        """The resident artifact of `scene`, or None; no LRU touch."""
+        e = self._entries.get(scene)
+        return None if e is None else e.artifact
+
     def add(self, scene: str, artifact) -> CacheEntry:
         """Install a resident artifact (engine construction / explicit)."""
         e = CacheEntry(scene, artifact, int(self._size_fn(artifact)))
@@ -186,6 +192,13 @@ class FusedDeviceStep:
     eviction and reload, so re-admitting a hot scene does not re-pay its
     growth retraces. Derived spec/rcfg rebuild only when the artifact
     object actually changes (reload).
+
+    A proposal-sampled artifact (Nerfacto, `artifact.proposal_sampled`)
+    takes none of that: every ray is a fixed number of samples, so there
+    is no budget and no growth retrace, and no pose-cache tier, since
+    plans index an occupancy grid. Each of its slots runs two programs
+    back to back, the proposal passes and then the main field's shading,
+    with no host sync between them.
     """
 
     def __init__(self, cfg: EngineConfig, recorder: obs.Recorder):
@@ -271,10 +284,13 @@ class FusedDeviceStep:
     # ------------------------------------------------------------------
     # Pose-cache tiers (the `step_items` serve fast path)
     # ------------------------------------------------------------------
-    def pose_key(self, scene: str, ro: np.ndarray, rd: np.ndarray):
+    def pose_key(self, scene: str, ro: np.ndarray, rd: np.ndarray,
+                 artifact=None):
         """(scene,) + pose-grid cell of a request bundle, None when the
-        pose cache is disabled."""
-        if self._pose_cache is None or ro.shape[0] == 0:
+        pose cache is disabled or the scene's resident `artifact` is
+        proposal-sampled."""
+        if (self._pose_cache is None or ro.shape[0] == 0
+                or getattr(artifact, "proposal_sampled", False)):
             return None
         from repro.nerf.pose_cache import pose_cell_key
 
@@ -421,6 +437,33 @@ class FusedDeviceStep:
             with rec.span("render.wait"):
                 return np.asarray(out)
 
+    def _propose_slot(self, artifact, it: WorkItem, ro: np.ndarray,
+                      rd: np.ndarray) -> np.ndarray:
+        """One live slot of a proposal-sampled artifact: the proposal
+        passes, then the main field's shading of the intervals they
+        place, dispatched back to back."""
+        import jax.numpy as jnp
+
+        from repro.nerf import fast_render as fr
+
+        rec, cfg = self.obs, artifact.cfg
+        kw = dict(cfg=cfg, use_pallas=self.cfg.use_pallas)
+        with rec.span("render.slot", rid=it.rid, seq=it.seq, tier="proposal"):
+            with rec.span("render.stage"):
+                ro_s, rd_s = jnp.asarray(ro), jnp.asarray(rd)
+            with rec.span("render.dispatch"):
+                edges = fr._slot_propose_impl(artifact.pack, ro_s, rd_s, **kw)
+            with rec.span("render.dispatch"):
+                out = fr._slot_shade_impl(
+                    artifact.pack, ro_s, rd_s, edges,
+                    early_stop=self.cfg.early_stop, **kw,
+                )
+            n = it.stop - it.start
+            rec.count("render.proposal_samples", n * cfg.proposal_samples_per_ray)
+            rec.count("render.shade_samples", n * cfg.shade_samples_per_ray)
+            with rec.span("render.wait"):
+                return np.asarray(out)
+
     def step_items(
         self, scene: str, artifact, items: List[WorkItem],
         ro: np.ndarray, rd: np.ndarray,
@@ -434,6 +477,12 @@ class FusedDeviceStep:
         runs at the same fixed (slot_rays, 3) padded shape, so mixing
         tiers within a bucket never retraces anything.
         """
+        if getattr(artifact, "proposal_sampled", False):
+            colors = np.zeros((ro.shape[0], ro.shape[1], 3), np.float32)
+            for slot, it in enumerate(items):
+                colors[slot] = self._propose_slot(artifact, it, ro[slot],
+                                                  rd[slot])
+            return colors
         if self.cfg.compaction != "march":
             # Legacy scatter strategy has no tiers: one padded-bucket call.
             return np.asarray(self(scene, artifact, ro, rd))
@@ -638,7 +687,7 @@ class ServeEngine:
         if self._t_first_submit is None:
             self._t_first_submit = now
         pose_key = (
-            self._stepper.pose_key(scene, ro, rd)
+            self._stepper.pose_key(scene, ro, rd, self._cache.peek(scene))
             if self._stepper is not None else None
         )
         if self._stepper is not None:

@@ -68,6 +68,7 @@ from repro.kernels.ops import (
     ray_march as ops_ray_march,
 )
 from repro.kernels.repack import DEFAULT_TILE_BK, repack_tile_native
+from repro.nerf import nerfacto
 from repro.nerf.hash_encoding import level_corner_data
 from repro.nerf.ngp import (
     NGPConfig,
@@ -129,6 +130,10 @@ class FusedPack:
     layout: str = "planar"
     compute: Dict[str, jnp.ndarray] = dataclasses.field(default_factory=dict)
 
+    def fields(self):
+        """(name, pack) of each field: this one, named ""."""
+        return [("", self)]
+
 
 jax.tree_util.register_dataclass(
     FusedPack,
@@ -171,6 +176,19 @@ def build_fused_pack(
     """
     if spec is None:
         spec = no_quant_spec(cfg)
+    return pack_field(params, ngp_linear_names(cfg), params["hash"], spec,
+                      layout)
+
+
+def pack_field(
+    params: Dict,
+    names,
+    hash_tables: Dict[str, jnp.ndarray],
+    spec: NGPQuantSpec,
+    layout: str = f"tile:{DEFAULT_TILE_BK}",
+) -> FusedPack:
+    """`build_fused_pack` for any field: the linears `names` (in the
+    spec's order) and the `level_<l>` tables of `hash_tables`."""
     wb = np.asarray(spec.weight_bits, np.float32)
     ab = np.asarray(spec.act_bits, np.float32)
     ar = np.asarray(spec.act_ranges, np.float32)
@@ -179,7 +197,7 @@ def build_fused_pack(
 
     layers: Dict[str, Dict[str, jnp.ndarray]] = {}
     modes = []
-    for i, name in enumerate(ngp_linear_names(cfg)):
+    for i, name in enumerate(names):
         w, b = params[name]["w"], params[name]["b"]
         wbi, abi = float(wb[i]), float(ab[i])
         lo, hi = float(ar[i, 0]), float(ar[i, 1])
@@ -220,8 +238,8 @@ def build_fused_pack(
             modes.append("float")
 
     tables: Dict[str, jnp.ndarray] = {}
-    for l in range(cfg.hash.n_levels):
-        t = params["hash"][f"level_{l}"]
+    for l in range(len(hash_tables)):
+        t = hash_tables[f"level_{l}"]
         bits = float(hb[l])
         if bits <= 8.0:
             # Integer codes + scale, bit-packed: hash bits shrink the pack.
@@ -341,6 +359,56 @@ def _fused_linear(pack: FusedPack, i: int, name: str, x, use_pallas):
     return x @ _fused_weight_f32(pack, name) + lyr["b"]
 
 
+def _fused_first_linear(pack: FusedPack, points, hcfg, name: str,
+                        use_pallas, corner_data=None):
+    """Hash encode of `points` (P, 3) in [0, 1] over the pack's tables,
+    then its first linear `name` (mode 0): the pre-activation (P, N).
+
+    With a repacked pack (`pack.compute` staged) the encode is the fused
+    `ops.hash_encode` over the staged concatenated table — this keeps
+    per-level `dequantize()` out of the jitted hot path, where XLA:CPU
+    fuses it into every gather lane — and, on the kernel path, the
+    linear folds into `ops.fused_field_query`."""
+    L = hcfg.n_levels
+    if "table_cat" in pack.compute:
+        if corner_data is None:
+            per_level = [level_corner_data(points, l, hcfg)
+                         for l in range(L)]
+            idx = jnp.stack([i for i, _ in per_level])  # (L, P, 8)
+            w = jnp.stack([w_ for _, w_ in per_level])
+        else:
+            idx, w = corner_data
+        cat = pack.compute["table_cat"]
+        rows = tuple(hcfg.level_entries(l) for l in range(L))
+        if pack.modes[0] == "int" and _use_kernels(use_pallas):
+            lyr = pack.layers[name]
+            return ops_fused_field_query(
+                idx, w, cat, rows, _layer_wq(pack, name), lyr,
+                use_pallas=use_pallas,
+            ) + lyr["b"]
+        enc = ops_hash_encode(idx, w, cat, rows)
+        return _fused_linear(pack, 0, name, enc, use_pallas)
+    # Storage-only pack (schema-v2 artifact loaded without repack):
+    # per-level gathers over tables dequantized inside the call.
+    feats = []
+    for l in range(L):
+        if corner_data is None:
+            idx, w = level_corner_data(points, l, hcfg)  # (P, 8)
+        else:
+            idx, w = corner_data[0][l], corner_data[1][l]
+        table = pack.hash_tables[f"level_{l}"]
+        if isinstance(table, PackedTensor):
+            # Stored form is integer codes in packed words; the gather
+            # runs over the dequantized grid (codes * scale), expanded
+            # inside the jitted call — DRAM holds the packed bytes.
+            table = table.dequantize()
+        vals = ops_hash_gather(idx.reshape(-1), table).reshape(
+            idx.shape + (hcfg.n_features,))
+        feats.append(jnp.sum(vals * w[..., None], axis=1))
+    enc = jnp.concatenate(feats, axis=-1)
+    return _fused_linear(pack, 0, name, enc, use_pallas)
+
+
 def fused_ngp_apply(
     pack: FusedPack,
     points: jnp.ndarray,  # (P, 3) in [0, 1]
@@ -353,54 +421,11 @@ def fused_ngp_apply(
     """Integer-mode field query. Mirrors `ngp_apply`'s fake-quant forward;
     exact up to float roundoff (integer accumulation where lowered).
     `corner_data` / `sh` take the geometry-only work precomputed by a
-    `CullPlan` for fixed sample points.
-
-    With a repacked pack (`pack.compute` staged) the encode is the fused
-    `ops.hash_encode` over the staged concatenated table —
-    this keeps per-level `dequantize()` out of the jitted hot path, where
-    XLA:CPU fuses it into every gather lane — and, on the kernel path,
-    the first linear folds into `ops.fused_field_query`."""
+    `CullPlan` for fixed sample points. The encode and the first linear
+    are `_fused_first_linear`'s."""
     names = ngp_linear_names(cfg)
-    L = cfg.hash.n_levels
-    if "table_cat" in pack.compute:
-        if corner_data is None:
-            per_level = [level_corner_data(points, l, cfg.hash)
-                         for l in range(L)]
-            idx = jnp.stack([i for i, _ in per_level])  # (L, P, 8)
-            w = jnp.stack([w_ for _, w_ in per_level])
-        else:
-            idx, w = corner_data
-        cat = pack.compute["table_cat"]
-        rows = tuple(cfg.hash.level_entries(l) for l in range(L))
-        if pack.modes[0] == "int" and _use_kernels(use_pallas):
-            lyr = pack.layers[names[0]]
-            h = ops_fused_field_query(
-                idx, w, cat, rows, _layer_wq(pack, names[0]), lyr,
-                use_pallas=use_pallas,
-            ) + lyr["b"]
-        else:
-            enc = ops_hash_encode(idx, w, cat, rows)
-            h = _fused_linear(pack, 0, names[0], enc, use_pallas)
-    else:
-        # Storage-only pack (schema-v2 artifact loaded without repack):
-        # per-level gathers over tables dequantized inside the call.
-        feats = []
-        for l in range(L):
-            if corner_data is None:
-                idx, w = level_corner_data(points, l, cfg.hash)  # (P, 8)
-            else:
-                idx, w = corner_data[0][l], corner_data[1][l]
-            table = pack.hash_tables[f"level_{l}"]
-            if isinstance(table, PackedTensor):
-                # Stored form is integer codes in packed words; the gather
-                # runs over the dequantized grid (codes * scale), expanded
-                # inside the jitted call — DRAM holds the packed bytes.
-                table = table.dequantize()
-            vals = ops_hash_gather(idx.reshape(-1), table).reshape(
-                idx.shape + (cfg.hash.n_features,))
-            feats.append(jnp.sum(vals * w[..., None], axis=1))
-        enc = jnp.concatenate(feats, axis=-1)
-        h = _fused_linear(pack, 0, names[0], enc, use_pallas)
+    h = _fused_first_linear(pack, points, cfg.hash, names[0], use_pallas,
+                            corner_data)
     h = jax.nn.relu(h)
     h = _fused_linear(pack, 1, names[1], h, use_pallas)
     raw_sigma, geo = h[..., 0], h[..., 1:]
@@ -880,6 +905,105 @@ def _slot_warp_impl(
     if rcfg.white_bg:
         color = color + (1.0 - acc)
     return color
+
+
+# ---------------------------------------------------------------------------
+# Nerfacto: three packed fields, two programs per slot.
+# ---------------------------------------------------------------------------
+@dataclasses.dataclass(frozen=True)
+class NerfactoPack:
+    """A Nerfacto field's packed inference form: one `FusedPack` per
+    field (the main field's five linears and its tables; each proposal
+    field's two linears and its tables) and the appearance input the
+    color MLP is served with (`nerfacto.serve_appearance`)."""
+
+    main: FusedPack
+    proposals: Tuple[FusedPack, ...]
+    appearance: jnp.ndarray  # (A,)
+
+    def fields(self):
+        """(name, pack) of each field; the main field's name is ""."""
+        return [("", self.main)] + [
+            (f"prop{k + 1}", p) for k, p in enumerate(self.proposals)]
+
+
+jax.tree_util.register_dataclass(
+    NerfactoPack, data_fields=["main", "proposals", "appearance"],
+    meta_fields=[],
+)
+
+
+def build_nerfacto_pack(
+    params: Dict,
+    cfg: nerfacto.NerfactoConfig,
+    spec: nerfacto.NerfactoQuantSpec,
+    layout: str = f"tile:{DEFAULT_TILE_BK}",
+) -> NerfactoPack:
+    """`pack_field` for each of the three fields (a concrete spec, as for
+    `build_fused_pack`)."""
+    main = pack_field(params, nerfacto.linear_names(cfg)[:5], params["hash"],
+                      spec.main, layout)
+    props = tuple(
+        pack_field(params, nerfacto.proposal_names(k),
+                   params[nerfacto.hash_key(k)], s, layout)
+        for k, s in enumerate(spec.proposals))
+    return NerfactoPack(main=main, proposals=props,
+                        appearance=nerfacto.serve_appearance(params))
+
+
+def fused_proposal_density(pack: FusedPack, x01, hcfg, k: int, use_pallas):
+    """Proposal field k's density (P,) at field coordinates (P, 3)."""
+    a, b = nerfacto.proposal_names(k)
+    h = jax.nn.relu(_fused_first_linear(pack, x01, hcfg, a, use_pallas))
+    return jnp.exp(_fused_linear(pack, 1, b, h, use_pallas)[:, 0])
+
+
+def fused_nerfacto_field(pack: NerfactoPack, x01, dirs, cfg, use_pallas):
+    """The main field's (density (P,), rgb (P, 3)); the color MLP takes
+    [SH(dir), geometry features, the served appearance]."""
+    f, main = cfg.field, pack.main
+    names = nerfacto.linear_names(cfg)
+    h = jax.nn.relu(_fused_first_linear(main, x01, f.hash, names[0],
+                                        use_pallas))
+    h = _fused_linear(main, 1, names[1], h, use_pallas)
+    app = jnp.broadcast_to(pack.appearance, (x01.shape[0], cfg.appearance_dim))
+    c = jnp.concatenate([sh_encode(dirs, f.sh_degree), h[:, 1:], app], -1)
+    c = jax.nn.relu(_fused_linear(main, 2, names[2], c, use_pallas))
+    c = jax.nn.relu(_fused_linear(main, 3, names[3], c, use_pallas))
+    rgb = jax.nn.sigmoid(_fused_linear(main, 4, names[4], c, use_pallas))
+    return jnp.exp(h[:, 0]), rgb
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "use_pallas"))
+def _slot_propose_impl(pack, rays_o, rays_d, *, cfg, use_pallas):
+    """The proposal passes of one slot (contraction, proposal 1, resample,
+    proposal 2, resample): the (R, n + 1) Euclidean edges of the
+    intervals the main field shades."""
+    fns = [functools.partial(fused_proposal_density, p, hcfg=h, k=k,
+                             use_pallas=use_pallas)
+           for k, (p, h) in enumerate(zip(pack.proposals, cfg.proposals))]
+    bins_list, _ = nerfacto.propose(fns, rays_o, rays_d, cfg)
+    return nerfacto.spacing_to_euclidean(bins_list[-1], cfg)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("cfg", "use_pallas", "early_stop"),
+)
+def _slot_shade_impl(pack, rays_o, rays_d, edges, *, cfg, use_pallas,
+                     early_stop):
+    """The main field at the slot's interval midpoints, composited over
+    the intervals' own lengths on a white background: colors (R, 3)."""
+    pts, delta = nerfacto.sample_points(rays_o, rays_d, edges)
+    R, S = delta.shape
+    x01, sel = nerfacto.field_coords(pts.reshape(-1, 3))
+    dirs = jnp.broadcast_to(rays_d[:, None, :], pts.shape).reshape(-1, 3)
+    sigma, rgb = fused_nerfacto_field(pack, x01, dirs, cfg, use_pallas)
+    sigma = jnp.where(sel, sigma, 0.0).reshape(R, S)
+    color, acc = ops_alpha_composite(
+        sigma, rgb.reshape(R, S, 3), delta, use_pallas=use_pallas,
+        early_stop=early_stop,
+    )
+    return color + (1.0 - acc)
 
 
 class FastRenderEngine:

@@ -506,4 +506,7 @@ def test_paper_scale_builds_the_paper_widths():
     scale = SceneScale.paper()
     assert scale.ngp_config() == paper()
     fp = ClosedLoopConfig(scale=scale).fingerprint()["scale"]
-    assert fp["base_res"] == 16 and fp["sh_degree"] == 4
+    # 16 SH coefficients (bands 0-3) is the default degree, so it stays
+    # out of the fingerprint; the base resolution does not.
+    assert scale.sh_degree == 3 and paper().sh_dim == 16
+    assert fp["base_res"] == 16 and "sh_degree" not in fp

@@ -19,12 +19,13 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.configs.ngp import paper
+from repro.configs.ngp import nerfacto, paper
 from repro.kernels.alpha_composite import alpha_composite
 from repro.kernels.autotune import RAY_MARCH_DEFAULT
 from repro.kernels.ops import hash_encode
 from repro.kernels.quant_matmul import quant_matmul_packed
 from repro.kernels.ray_march import ray_march
+from repro.nerf.nerfacto import linear_dims as nerfacto_linear_dims
 from repro.nerf.ngp import _linear_dims
 
 POINTS = 4096  # field-query batch: culled samples per serve chunk
@@ -61,7 +62,20 @@ def _compiled_text(fn, *args) -> str:
 @pytest.mark.parametrize("layout", ["planar", "tile:128"])
 @pytest.mark.parametrize("bits", [4, 6, 8])
 def test_quant_matmul_packed_compiles_at_paper_mlp_shapes(spec, bits, layout):
-    dims = _linear_dims(paper())
+    _compile_packed_mlp(spec, _linear_dims(paper()), bits, layout)
+
+
+@pytest.mark.parametrize("bits", [4, 8])
+def test_quant_matmul_packed_compiles_at_nerfacto_shapes(spec, bits):
+    """Nerfacto's linears beyond Instant-NGP's: color/0 with K = 63 (SH,
+    geometry and appearance), the proposal fields' 10 -> 16 -> 1."""
+    dims = nerfacto_linear_dims(nerfacto())
+    _compile_packed_mlp(spec, {n: dims[n] for n in
+                               ("color/0", "prop1/0", "prop1/1")},
+                        bits, "tile:128")
+
+
+def _compile_packed_mlp(spec, dims, bits, layout):
     bk = 128
     args, shapes = [], []
     for k, n in dims.values():
@@ -114,7 +128,15 @@ def test_ray_march_compiles_at_g32(spec):
 
 @pytest.mark.parametrize("early_stop", [False, True])
 def test_alpha_composite_compiles(spec, early_stop):
-    r, s = 4096, 64
+    _compile_composite(spec, 4096, 64, early_stop)
+
+
+def test_alpha_composite_compiles_at_nerfacto_slot(spec):
+    """One Nerfacto slot: 512 rays of 48 shaded samples."""
+    _compile_composite(spec, 512, 48, True)
+
+
+def _compile_composite(spec, r, s, early_stop):
     text = _compiled_text(
         lambda sg, rgb, d: alpha_composite(sg, rgb, d, interpret=False,
                                            early_stop=early_stop),
