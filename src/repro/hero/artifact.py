@@ -369,11 +369,15 @@ class QuantArtifact:
             modes = manifest["pack_modes"]
             if model != "nerfacto":
                 modes = {"": modes}
+                hcfgs = {"": cfg.hash}
+            else:
+                hcfgs = {"": cfg.field.hash, **{
+                    f"prop{k + 1}": h for k, h in enumerate(cfg.proposals)}}
             fields = {}
             for field, m in modes.items():
                 fields[field] = FusedPack(
                     layers=layers[field], hash_tables=tables[field],
-                    modes=tuple(m),
+                    modes=tuple(m), hash_cfg=hcfgs[field],
                 )
                 if layout != "planar":
                     fields[field] = repack_fused_pack(fields[field], layout)

@@ -329,6 +329,16 @@ class FusedDeviceStep:
             self._pose_cache.stats() if self._pose_cache is not None else None
         )
 
+    def _count_lookups(self, pack, points: int) -> None:
+        """The hash-table corner lookups of one field query at `points`
+        points (`hash.lookups`), and the part cell-major rows serve
+        (`hash.cell_lookups`)."""
+        from repro.nerf.fast_render import hash_lookups
+
+        per_point, cell_per_point = hash_lookups(pack)
+        self.obs.count("hash.lookups", points * per_point)
+        self.obs.count("hash.cell_lookups", points * cell_per_point)
+
     def _march_slot(self, st, artifact, ro_s, rd_s) -> np.ndarray:
         """Cache-miss tier for one padded slot, with grow-on-overflow:
         the march impl returns the TRUE device active count, so an
@@ -347,6 +357,9 @@ class FusedDeviceStep:
                     early_stop=self.cfg.early_stop,
                 )
             budget = st["budget"]
+            points = ro_s.shape[0] * st["rcfg"].n_samples
+            self._count_lookups(artifact.pack, points if budget is None
+                                else min(budget, points))
             with rec.span("render.wait"):
                 if budget is None:
                     return np.asarray(color)
@@ -410,6 +423,7 @@ class FusedDeviceStep:
                         artifact.params, artifact.pack, st["spec"],
                         artifact.occ, ro_s, rd_s, plan.plan_row, **kw,
                     )
+                self._count_lookups(artifact.pack, plan.plan_row[0].shape[0])
             elif tier == "warp":
                 cache.warps += 1
                 with rec.span("render.dispatch"):
@@ -418,6 +432,7 @@ class FusedDeviceStep:
                         artifact.occ, ro_s, rd_s, plan.inv_take, plan.take,
                         plan.valid_cons, **kw,
                     )
+                self._count_lookups(artifact.pack, plan.inv_take.shape[0])
             else:
                 if cache is not None and key is not None:
                     cache.misses += 1
@@ -461,6 +476,12 @@ class FusedDeviceStep:
             n = it.stop - it.start
             rec.count("render.proposal_samples", n * cfg.proposal_samples_per_ray)
             rec.count("render.shade_samples", n * cfg.shade_samples_per_ray)
+            R = ro.shape[0]  # the programs query every ray of the slot
+            for p, s in zip(artifact.pack.proposals,
+                            (cfg.n_initial,) + cfg.n_resampled[:-1]):
+                self._count_lookups(p, R * s)
+            self._count_lookups(artifact.pack.main,
+                                R * cfg.shade_samples_per_ray)
             with rec.span("render.wait"):
                 return np.asarray(out)
 

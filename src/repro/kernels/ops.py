@@ -102,43 +102,87 @@ def quant_matmul_packed(x_codes, wq, sx, sw, zx, use_pallas="auto", **kw):
     )
 
 
-def hash_encode(corner_idx, corner_w, table_cat, level_rows):
-    """Fused multi-level hash-grid encode: ONE XLA gather over the
-    concatenated table for all levels + trilinear interpolation.
+def hash_encode(corner_idx, corner_w, table_cat, level_rows,
+                cells_cat=None, direct=()):
+    """Fused multi-level hash-grid encode: XLA gathers of the corner rows
+    + trilinear interpolation.
 
     corner_idx    (L, B, 8) int32 — per-level in-table corner indices
     corner_w      (L, B, 8) f32   — matching trilinear weights
     table_cat     (T, F)    f32   — all level tables stacked row-wise
     level_rows    L static ints   — each level's row count in table_cat
+    cells_cat     (D, 8*F)  f32   — cell-major rows of the direct-indexed
+                                    levels, stacked in level order: row c
+                                    of a level's block holds its 8 corner
+                                    rows of the cell whose corner 0 is row
+                                    c (`fast_render.repack_fused_pack`)
+    direct        L static bools  — which levels read `cells_cat`; empty
+                                    for none
+
+    A hashed level gathers 8 rows of `table_cat` a point, all such levels
+    in ONE gather; a direct level gathers ONE row of `cells_cat` a point,
+    at its corner-0 index, all such levels in one more gather. With no
+    direct level the program is the single gather alone.
 
     Returns (B, L*F) features in level-major column order — bit-identical
     to gathering each level's table separately and concatenating (pinned
-    by tests).
+    by tests on the CPU; on a TPU the trilinear sum over cell-row values
+    may round differently in the last bit).
     """
     L, B, C = corner_idx.shape
     rows = [int(r) for r in level_rows]
     if len(rows) != L or sum(rows) != table_cat.shape[0]:
         raise ValueError(f"level_rows {rows} do not split a "
                          f"{table_cat.shape[0]}-row table into {L} levels")
+    direct = [bool(d) for d in direct] or [False] * L
+    if len(direct) != L:
+        raise ValueError(f"direct has {len(direct)} flags for {L} levels")
     offs = np.concatenate([[0], np.cumsum(rows)[:-1]]).astype(np.int32)
-    flat = (corner_idx + jnp.asarray(offs)[:, None, None]).reshape(-1)
-    vals = hash_gather(flat, table_cat).reshape(L, B, C, -1)
+    if not any(direct):
+        flat = (corner_idx + jnp.asarray(offs)[:, None, None]).reshape(-1)
+        vals = hash_gather(flat, table_cat).reshape(L, B, C, -1)
+    else:
+        cell_lv = [l for l in range(L) if direct[l]]
+        hash_lv = [l for l in range(L) if not direct[l]]
+        cell_rows = [rows[l] for l in cell_lv]
+        if cells_cat is None or cells_cat.shape != (
+                sum(cell_rows), C * table_cat.shape[1]):
+            raise ValueError(
+                f"direct levels {cell_lv} need a ({sum(cell_rows)}, "
+                f"{C * table_cat.shape[1]}) cell-major table")
+        coffs = np.concatenate([[0], np.cumsum(cell_rows)[:-1]])
+        cell_idx = jnp.stack([corner_idx[l, :, 0] + int(o)
+                              for l, o in zip(cell_lv, coffs)])
+        cell = hash_gather(cell_idx.reshape(-1), cells_cat).reshape(
+            len(cell_lv), B, C, -1)
+        part = {l: cell[i] for i, l in enumerate(cell_lv)}
+        if hash_lv:
+            hash_idx = jnp.stack([corner_idx[l] + int(offs[l])
+                                  for l in hash_lv])
+            hashed = hash_gather(hash_idx.reshape(-1), table_cat).reshape(
+                len(hash_lv), B, C, -1)
+            part.update({l: hashed[i] for i, l in enumerate(hash_lv)})
+        vals = jnp.stack([part[l] for l in range(L)])
     feats = jnp.sum(vals * corner_w[..., None], axis=2)  # (L, B, F)
     return jnp.moveaxis(feats, 0, 1).reshape(B, -1)
 
 
 def fused_field_query(corner_idx, corner_w, table_cat, level_rows,
-                      wq, act, use_pallas="auto", **kw):
+                      wq, act, use_pallas="auto", cells_cat=None, direct=(),
+                      **kw):
     """hash_encode -> quantized matmul, the fused first-layer field query
     of `FastRenderEngine`'s integer path.
 
+    `cells_cat` / `direct` are `hash_encode`'s: the cell-major rows of the
+    direct-indexed levels and which levels read them.
     `act` carries the activation grid of the first linear layer (the
     FusedPack layer dict fields): sx scale, zx int zero point (int8-
     shifted), zx_f float zero point, qmax code ceiling, off int8 shift.
     `wq` is the layer's `PackedTensor` (planar or tile-native). Returns
     the f32 pre-activation (B, N).
     """
-    enc = hash_encode(corner_idx, corner_w, table_cat, level_rows)
+    enc = hash_encode(corner_idx, corner_w, table_cat, level_rows,
+                      cells_cat, direct)
     codes = jnp.clip(jnp.round(enc / act["sx"] + act["zx_f"]), 0.0,
                      act["qmax"])
     ci8 = (codes - act["off"]).astype(jnp.int8)

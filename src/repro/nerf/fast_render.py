@@ -69,7 +69,11 @@ from repro.kernels.ops import (
 )
 from repro.kernels.repack import DEFAULT_TILE_BK, repack_tile_native
 from repro.nerf import nerfacto
-from repro.nerf.hash_encoding import level_corner_data
+from repro.nerf.hash_encoding import (
+    HashEncodingConfig,
+    cell_corner_rows,
+    level_corner_data,
+)
 from repro.nerf.ngp import (
     NGPConfig,
     NGPQuantSpec,
@@ -119,9 +123,11 @@ class FusedPack:
     words) — what the artifact serializes and `model_bytes` measures.
     `compute` holds the derived kernel-native forms staged once by
     `repack_fused_pack` (`layout` records which repack): tile-native
-    packed words per layer, the concatenated dequantized hash table for
-    the fused encode, float weight carriers. Dropping `compute` loses
-    speed, never data.
+    packed words per layer, the concatenated dequantized hash table and
+    the direct-indexed levels' cell-major table for the fused encode,
+    float weight carriers. Dropping `compute` loses speed, never data.
+    `hash_cfg` is the tables' encoding (which levels are direct-indexed,
+    at what resolution); a pack without it stages no cell-major table.
     """
 
     layers: Dict[str, Dict[str, jnp.ndarray]]
@@ -129,6 +135,7 @@ class FusedPack:
     modes: Tuple[str, ...]
     layout: str = "planar"
     compute: Dict[str, jnp.ndarray] = dataclasses.field(default_factory=dict)
+    hash_cfg: Optional[HashEncodingConfig] = None
 
     def fields(self):
         """(name, pack) of each field: this one, named ""."""
@@ -138,7 +145,7 @@ class FusedPack:
 jax.tree_util.register_dataclass(
     FusedPack,
     data_fields=["layers", "hash_tables", "compute"],
-    meta_fields=["modes", "layout"],
+    meta_fields=["modes", "layout", "hash_cfg"],
 )
 
 
@@ -176,19 +183,21 @@ def build_fused_pack(
     """
     if spec is None:
         spec = no_quant_spec(cfg)
-    return pack_field(params, ngp_linear_names(cfg), params["hash"], spec,
-                      layout)
+    return pack_field(params, ngp_linear_names(cfg), params["hash"],
+                      cfg.hash, spec, layout)
 
 
 def pack_field(
     params: Dict,
     names,
     hash_tables: Dict[str, jnp.ndarray],
+    hcfg: HashEncodingConfig,
     spec: NGPQuantSpec,
     layout: str = f"tile:{DEFAULT_TILE_BK}",
 ) -> FusedPack:
     """`build_fused_pack` for any field: the linears `names` (in the
-    spec's order) and the `level_<l>` tables of `hash_tables`."""
+    spec's order) and the `level_<l>` tables of `hash_tables`, encoded
+    as `hcfg` says."""
     wb = np.asarray(spec.weight_bits, np.float32)
     ab = np.asarray(spec.act_bits, np.float32)
     ar = np.asarray(spec.act_ranges, np.float32)
@@ -249,7 +258,8 @@ def pack_field(
             tables[f"level_{l}"] = fake_quant_weight(t, qp)
         else:
             tables[f"level_{l}"] = t
-    pack = FusedPack(layers=layers, hash_tables=tables, modes=tuple(modes))
+    pack = FusedPack(layers=layers, hash_tables=tables, modes=tuple(modes),
+                     hash_cfg=hcfg)
     return repack_fused_pack(pack, layout) if layout != "planar" else pack
 
 
@@ -265,6 +275,14 @@ def repack_fused_pack(
                         encode has no per-level dequantize inside the
                         jitted hot path (level row counts are static:
                         `cfg.hash.level_entries`);
+      "cells_cat"       (sum_d T_d, 8F) f32 — for each direct-indexed
+                        level d of `pack.hash_cfg` (`is_direct`), its
+                        dequantized rows regathered cell-major: row c
+                        holds the 8 corner rows of the cell whose corner 0
+                        is row c (`hash_encoding.cell_corner_rows`), so the
+                        encode gathers one row a point there, not eight.
+                        Absent when the pack has no `hash_cfg` or the
+                        field no direct level;
       "<name>::wq_tile" tile-native `PackedTensor` per packed layer (the
                         `kernels/repack.py` permutation the matmul
                         kernel unpacks with a single broadcast shift);
@@ -283,6 +301,13 @@ def repack_fused_pack(
         t = pack.hash_tables[f"level_{l}"]
         tabs.append(t.dequantize() if isinstance(t, PackedTensor) else t)
     compute["table_cat"] = jnp.concatenate(tabs, axis=0)
+    hcfg = pack.hash_cfg
+    res = [] if hcfg is None else hcfg.resolutions()
+    cells = [jnp.take(tabs[l], jnp.asarray(cell_corner_rows(res[l])), axis=0)
+             .reshape(tabs[l].shape[0], -1)
+             for l in range(len(res)) if hcfg.is_direct(l)]
+    if cells:
+        compute["cells_cat"] = jnp.concatenate(cells, axis=0)
     for name, lyr in pack.layers.items():
         if "wq" in lyr:
             compute[f"{name}::wq_tile"] = repack_tile_native(lyr["wq"], bk)
@@ -308,6 +333,16 @@ def fused_pack_stored_bytes(pack: FusedPack) -> int:
         else:
             total += int(np.size(tab)) * 4
     return total
+
+
+def hash_lookups(pack: FusedPack) -> Tuple[int, int]:
+    """Corner lookups one point costs in this field's encode: (all of
+    them, 8 a level; the part served by cell-major rows, 8 for each
+    direct-indexed level when `cells_cat` is staged)."""
+    L, hcfg = len(pack.hash_tables), pack.hash_cfg
+    if "cells_cat" not in pack.compute:
+        return 8 * L, 0
+    return 8 * L, 8 * sum(hcfg.is_direct(l) for l in range(L))
 
 
 def _use_kernels(use_pallas) -> bool:
@@ -365,10 +400,11 @@ def _fused_first_linear(pack: FusedPack, points, hcfg, name: str,
     then its first linear `name` (mode 0): the pre-activation (P, N).
 
     With a repacked pack (`pack.compute` staged) the encode is the fused
-    `ops.hash_encode` over the staged concatenated table — this keeps
-    per-level `dequantize()` out of the jitted hot path, where XLA:CPU
-    fuses it into every gather lane — and, on the kernel path, the
-    linear folds into `ops.fused_field_query`."""
+    `ops.hash_encode` over the staged tables — the concatenated table for
+    hashed levels, the cell-major one for direct-indexed levels where it
+    is staged — which keeps per-level `dequantize()` out of the jitted
+    hot path, where XLA:CPU fuses it into every gather lane; on the
+    kernel path the linear folds into `ops.fused_field_query`."""
     L = hcfg.n_levels
     if "table_cat" in pack.compute:
         if corner_data is None:
@@ -380,13 +416,16 @@ def _fused_first_linear(pack: FusedPack, points, hcfg, name: str,
             idx, w = corner_data
         cat = pack.compute["table_cat"]
         rows = tuple(hcfg.level_entries(l) for l in range(L))
+        cells = pack.compute.get("cells_cat")
+        direct = (() if cells is None
+                  else tuple(hcfg.is_direct(l) for l in range(L)))
         if pack.modes[0] == "int" and _use_kernels(use_pallas):
             lyr = pack.layers[name]
             return ops_fused_field_query(
                 idx, w, cat, rows, _layer_wq(pack, name), lyr,
-                use_pallas=use_pallas,
+                use_pallas=use_pallas, cells_cat=cells, direct=direct,
             ) + lyr["b"]
-        enc = ops_hash_encode(idx, w, cat, rows)
+        enc = ops_hash_encode(idx, w, cat, rows, cells, direct)
         return _fused_linear(pack, 0, name, enc, use_pallas)
     # Storage-only pack (schema-v2 artifact loaded without repack):
     # per-level gathers over tables dequantized inside the call.
@@ -942,10 +981,10 @@ def build_nerfacto_pack(
     """`pack_field` for each of the three fields (a concrete spec, as for
     `build_fused_pack`)."""
     main = pack_field(params, nerfacto.linear_names(cfg)[:5], params["hash"],
-                      spec.main, layout)
+                      cfg.field.hash, spec.main, layout)
     props = tuple(
         pack_field(params, nerfacto.proposal_names(k),
-                   params[nerfacto.hash_key(k)], s, layout)
+                   params[nerfacto.hash_key(k)], cfg.proposals[k], s, layout)
         for k, s in enumerate(spec.proposals))
     return NerfactoPack(main=main, proposals=props,
                         appearance=nerfacto.serve_appearance(params))

@@ -135,6 +135,20 @@ def level_corner_data(
     return idx, w
 
 
+def cell_corner_rows(res: int) -> np.ndarray:
+    """Rows of a direct-indexed level's 8 corners for every cell origin:
+    (r, 8) int32 with r = (res+1)^3, row c for the cell whose corner 0 has
+    dense index c, corners in `_CORNERS` order and clipped at `res`
+    exactly as `level_corner_data` clips them. A table gathered by these
+    rows serves a point's 8 corners from its corner-0 index alone."""
+    s = res + 1
+    c = np.arange(s ** 3, dtype=np.int64)
+    x0 = np.stack([c % s, (c // s) % s, c // (s * s)], axis=-1)  # (r, 3)
+    corners = np.minimum(x0[:, None, :] + _CORNERS[None], res)  # (r, 8, 3)
+    return (corners[..., 0] + corners[..., 1] * s
+            + corners[..., 2] * s * s).astype(np.int32)
+
+
 def hash_encode(
     tables: Dict[str, jnp.ndarray],
     points: jnp.ndarray,

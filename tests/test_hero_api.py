@@ -306,6 +306,26 @@ def test_tile_repack_invisible_on_disk(tiny_artifact, tmp_path):
             np.testing.assert_array_equal(z1[k], z2[k])
 
 
+def test_load_restages_the_cell_major_table(tiny_artifact, tmp_path):
+    """The cell-major table of the direct-indexed levels is staged at
+    load as at compile, from the stored tables and the manifest's config,
+    and never reaches disk: the stored bytes are unchanged."""
+    from repro.nerf.fast_render import fused_pack_stored_bytes
+
+    hcfg = tiny_artifact.cfg.hash
+    assert [hcfg.is_direct(l) for l in range(4)] == [True, False, False, False]
+    loaded = hero.QuantArtifact.load(tiny_artifact.save(tmp_path / "a"))
+    assert loaded.pack.hash_cfg == hcfg
+    want = tiny_artifact.pack.compute["cells_cat"]
+    assert want.shape == (5 ** 3, 8 * 2)
+    np.testing.assert_array_equal(np.asarray(loaded.pack.compute["cells_cat"]),
+                                  np.asarray(want))
+    assert fused_pack_stored_bytes(loaded.pack) == \
+        fused_pack_stored_bytes(tiny_artifact.pack)
+    planar = hero.QuantArtifact.load(tmp_path / "a", layout="planar")
+    assert "cells_cat" not in planar.pack.compute
+
+
 def test_planar_layout_load_serves_identically(tiny_env, tiny_artifact,
                                                tmp_path):
     """layout="planar" opts out of the compile-time repack (storage-only

@@ -238,32 +238,108 @@ def test_hash_encode_routing_across_onehot_domain(use_pallas):
     np.testing.assert_array_equal(np.asarray(got_h), np.asarray(want_h))
 
 
-def _bench_level_rows(name):
+_HASH_KEYS = ("n_levels", "n_features", "log2_table_size", "base_resolution",
+              "max_resolution")
+
+
+def _bench_hash_cfg(name, F=2):
+    """The hash encoding of a benchmark configuration (`nerfacto-prop<k>`
+    names Nerfacto's proposal field k), with F features a level."""
     import json
     from pathlib import Path
 
     from repro.nerf.hash_encoding import HashEncodingConfig
 
+    conf, _, prop = name.partition("-prop")
     path = Path(__file__).resolve().parents[1] / "bench" / "configs" / \
-        f"{name}.json"
+        f"{conf}.json"
     m = json.loads(path.read_text())["model"]
-    cfg = HashEncodingConfig(**{k: m[k] for k in (
-        "n_levels", "n_features", "log2_table_size", "base_resolution",
-        "max_resolution")})
-    return tuple(cfg.level_entries(l) for l in range(cfg.n_levels))
+    if prop:
+        m = m["proposals"][int(prop) - 1]
+    return HashEncodingConfig(**{**{k: m[k] for k in _HASH_KEYS},
+                                 "n_features": F})
+
+
+def _points_with_boundary(n, seed):
+    """Points in [0, 1]^3 with coordinates at exactly 1.0 (corner 0 at
+    x0 = res, its upper corners clipped) and 0.0, on each axis and all."""
+    rng = np.random.RandomState(seed)
+    pts = rng.rand(n, 3).astype(np.float32)
+    for a in range(3):
+        pts[a, a] = 1.0
+        pts[3 + a, a] = 0.0
+    pts[6] = 1.0
+    pts[7] = 0.0
+    return jnp.asarray(pts)
+
+
+def _stage_cells(tables, hcfg):
+    """The cell-major table of the direct levels, staged from the level
+    tables as `repack_fused_pack` stages it."""
+    from repro.nerf.hash_encoding import cell_corner_rows
+
+    res = hcfg.resolutions()
+    return jnp.concatenate([
+        jnp.take(t, jnp.asarray(cell_corner_rows(res[l])), axis=0)
+        .reshape(t.shape[0], -1)
+        for l, t in enumerate(tables) if hcfg.is_direct(l)])
+
+
+# Direct-indexed levels of each benchmark field: resolutions 16, 22, 30,
+# 42, 58 at T=2^19; 16, 22 at T=2^14; 16, 26, 45 and 16, 32 at T=2^17.
+_DIRECT_LEVELS = {"ngp-paper-t19": 5, "ngp-paper-t14": 2,
+                  "nerfacto-prop1": 3, "nerfacto-prop2": 2}
 
 
 @pytest.mark.parametrize("F", [2, 4])
-@pytest.mark.parametrize("config", ["ngp-paper-t19", "ngp-paper-t14"])
+@pytest.mark.parametrize("config", ["ngp-paper-t19", "ngp-paper-t14",
+                                    "nerfacto-prop1", "nerfacto-prop2"])
 def test_hash_encode_bit_identical_at_bench_level_sizes(config, F):
-    """All 16 levels of each benchmark configuration (4,913 rows up to
-    the full table) through the one gather over the concatenated table."""
-    rows = _bench_level_rows(config)
-    assert len(rows) == 16 and min(rows) == 17 ** 3
+    """Every level of each benchmark field at its real row count (4,913
+    rows up to the full table): the one gather over the concatenated
+    table; the cell-major encode of the direct-indexed levels, from real
+    points on the boundary cells too; and the field's first layer fed the
+    precomputed (L, P, 8) corner data a CullPlan passes — each
+    bit-identical to gathering each level's table separately."""
+    from repro.nerf import fast_render as fr
+    from repro.nerf.hash_encoding import level_corner_data
+
+    hcfg = _bench_hash_cfg(config, F)
+    L = hcfg.n_levels
+    rows = tuple(hcfg.level_entries(l) for l in range(L))
+    direct = tuple(hcfg.is_direct(l) for l in range(L))
+    assert min(rows) == 17 ** 3 and sum(direct) == _DIRECT_LEVELS[config]
     tables, idx, w = _level_inputs(rows, F=F, B=24, seed=F)
-    got = ops.hash_encode(idx, w, jnp.concatenate(tables), rows)
+    cat = jnp.concatenate(tables)
+    got = ops.hash_encode(idx, w, cat, rows)
     np.testing.assert_array_equal(np.asarray(got),
                                   np.asarray(_per_level_take(tables, idx, w)))
+
+    pts = _points_with_boundary(40, seed=F)
+    per_level = [level_corner_data(pts, l, hcfg) for l in range(L)]
+    idx = jnp.stack([i for i, _ in per_level])
+    w = jnp.stack([w_ for _, w_ in per_level])
+    cells = _stage_cells(tables, hcfg)
+    want = _per_level_take(tables, idx, w)
+    got = ops.hash_encode(idx, w, cat, rows, cells, direct)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    rng = np.random.RandomState(F)
+    lin = {"w": jnp.asarray(rng.standard_normal((L * F, 16)), jnp.float32),
+           "b": jnp.asarray(rng.standard_normal(16), jnp.float32)}
+    planar = fr.FusedPack(
+        layers={"sigma/0": lin},
+        hash_tables={f"level_{l}": t for l, t in enumerate(tables)},
+        modes=("float",), hash_cfg=hcfg)
+    staged = fr.repack_fused_pack(planar)
+    np.testing.assert_array_equal(np.asarray(staged.compute["cells_cat"]),
+                                  np.asarray(cells))
+    want = fr._fused_first_linear(planar, pts, hcfg, "sigma/0", False,
+                                  corner_data=(idx, w))
+    for corner_data in ((idx, w), None):
+        got = fr._fused_first_linear(staged, pts, hcfg, "sigma/0", False,
+                                     corner_data=corner_data)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def _eqns(jaxpr):
@@ -283,22 +359,75 @@ def _eqns(jaxpr):
 
 @pytest.mark.parametrize("use_pallas", [True, False])
 def test_fused_field_query_is_one_gather_and_the_quant_kernel(use_pallas):
-    """The hash lookup is ONE gather over the concatenated table, and the
-    only Pallas kernel in the field query is the quantized matmul's."""
-    idx, w, _, cat, rows = _hash_inputs(L=4, B=29, T=32, F=2)
+    """The hash lookup is one gather over each staged table: a field with
+    no direct-indexed level has exactly one, over the concatenated table,
+    8 indices a point a level; with direct levels the cell-major table
+    takes one more, one index a point a direct level. The only Pallas
+    kernel in the field query is the quantized matmul's."""
+    L, B, T, F = 4, 29, 32, 2
+    idx, w, _, cat, rows = _hash_inputs(L=L, B=B, T=T, F=F)
+    cells = jnp.ones((2 * T, 8 * F), jnp.float32)
     wq = repack_tile_native(_packed(8, 16, 4, scale=0.03))
     act = {"sx": 0.05, "zx_f": 128.0, "qmax": 255.0, "off": 128,
            "zx": jnp.int32(0)}
-    jaxpr = jax.make_jaxpr(
-        lambda i, w_, c: ops.fused_field_query(i, w_, c, rows, wq, act,
-                                               use_pallas=use_pallas)
-    )(idx, w, cat).jaxpr
-    eqns = list(_eqns(jaxpr))
-    gathers = [e for e in eqns if e.primitive.name == "gather"]
-    assert [e.invars[0].aval.shape for e in gathers] == [cat.shape]
-    kernels = [e.params["jaxpr"].debug_info.func_name for e in eqns
-               if e.primitive.name == "pallas_call"]
-    assert kernels == (["_qmm_packed_kernel"] if use_pallas else [])
+
+    def gathers(*table_args):
+        jaxpr = jax.make_jaxpr(
+            lambda i, w_, c, *ce: ops.fused_field_query(
+                i, w_, c, rows, wq, act, use_pallas=use_pallas,
+                **dict(zip(("cells_cat", "direct"),
+                           ce + ((True, True, False, False),)))
+                if ce else {})
+        )(idx, w, cat, *table_args).jaxpr
+        eqns = list(_eqns(jaxpr))
+        kernels = [e.params["jaxpr"].debug_info.func_name for e in eqns
+                   if e.primitive.name == "pallas_call"]
+        assert kernels == (["_qmm_packed_kernel"] if use_pallas else [])
+        return sorted((e.invars[0].aval.shape, e.invars[1].aval.shape[0])
+                      for e in eqns if e.primitive.name == "gather")
+
+    assert gathers() == [(cat.shape, L * B * 8)]
+    assert gathers(cells) == sorted([(cells.shape, 2 * B),
+                                     (cat.shape, 2 * B * 8)])
+
+
+def _single_gather_encode(corner_idx, corner_w, table_cat, level_rows):
+    """The encode of a field with no direct-indexed level, as it was
+    before cell-major tables existed: one gather over the concatenated
+    table, the trilinear sum."""
+    L, B, C = corner_idx.shape
+    rows = [int(r) for r in level_rows]
+    offs = np.concatenate([[0], np.cumsum(rows)[:-1]]).astype(np.int32)
+    flat = (corner_idx + jnp.asarray(offs)[:, None, None]).reshape(-1)
+    vals = ref.hash_gather_ref(flat, table_cat).reshape(L, B, C, -1)
+    feats = jnp.sum(vals * corner_w[..., None], axis=2)
+    return jnp.moveaxis(feats, 0, 1).reshape(B, -1)
+
+
+def test_field_without_direct_levels_lowers_to_the_single_gather():
+    """A field with no direct-indexed level (and an unstaged pack) runs
+    exactly the single-gather program: the lowered text is the same."""
+    from repro.nerf import fast_render as fr
+    from repro.nerf.hash_encoding import HashEncodingConfig
+
+    hcfg = HashEncodingConfig(n_levels=3, log2_table_size=6,
+                              base_resolution=4, max_resolution=16)
+    assert not any(hcfg.is_direct(l) for l in range(3))
+    rows = tuple(hcfg.level_entries(l) for l in range(3))
+    idx, w, _, cat, _ = _hash_inputs(L=3, B=37, T=rows[0], F=2)
+    text = lambda f: jax.jit(f).lower(idx, w, cat).as_text()  # noqa: E731
+    want = text(lambda i, w_, c: _single_gather_encode(i, w_, c, rows))
+    for direct in ((), (False,) * 3):
+        assert text(lambda i, w_, c: ops.hash_encode(
+            i, w_, c, rows, None, direct)) == want
+
+    tables = {f"level_{l}": cat[sum(rows[:l]):sum(rows[:l + 1])]
+              for l in range(3)}
+    pack = fr.repack_fused_pack(fr.FusedPack(
+        layers={"a": {"w": jnp.ones((6, 4)), "b": jnp.zeros(4)}},
+        hash_tables=tables, modes=("float",), hash_cfg=hcfg))
+    assert "cells_cat" not in pack.compute
+    assert fr.hash_lookups(pack) == (24, 0)
 
 
 # ---------------------------------------------------------------------------

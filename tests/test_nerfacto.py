@@ -253,6 +253,13 @@ def test_artifact_round_trip_keeps_codes_and_serves_exactly(tmp_path):
                                           np.asarray(lyr["wq"].words))
     np.testing.assert_array_equal(np.asarray(back.pack.appearance),
                                   np.asarray(art.pack.appearance))
+    # Only the main field has a direct-indexed level (res 4 at T=2^8):
+    # its cell-major table is staged again at load.
+    assert [("cells_cat" in p.compute) for _, p in back.pack.fields()] == \
+        [True, False, False]
+    np.testing.assert_array_equal(
+        np.asarray(back.pack.main.compute["cells_cat"]),
+        np.asarray(art.pack.main.compute["cells_cat"]))
 
     o, d = rays(n=700, seed=3)
     _, want = programs(art.pack, o, d)
@@ -262,9 +269,35 @@ def test_artifact_round_trip_keeps_codes_and_serves_exactly(tmp_path):
     # arithmetic differently where it sits in another vector lane.
     np.testing.assert_allclose(got, np.asarray(want), atol=1e-6)
     s = svc.stats()
-    assert s["trace"]["counters"] == {"render.proposal_samples": 700 * 24,
-                                      "render.shade_samples": 700 * 8}
+    slot_points = 6 * 128  # 700 rays in 6 slots of 128
+    assert s["trace"]["counters"] == {
+        "render.proposal_samples": 700 * 24, "render.shade_samples": 700 * 8,
+        "hash.lookups": slot_points * (16 * 16 + 8 * 16 + 8 * 32),
+        "hash.cell_lookups": slot_points * 8 * 8}
     spans = s["trace"]["spans"]
     assert "pose.key" not in spans
     assert spans["render.dispatch"]["count"] == 2 * spans["render.slot"]["count"]
     assert s["budget_retraces"] == 0 and s["sample_budget"] is None
+
+
+def test_engine_counts_hash_lookups_per_proposal_slot(monkeypatch):
+    """Zero-render: with both slot programs stubbed, a proposal slot
+    counts each field's corner lookups at the points it is queried at
+    (every ray of the slot x the field's samples), 8 a level, and the
+    part the main field's one direct-indexed level serves from cell-major
+    rows; the proposal fields have none."""
+    from repro import hero
+    from repro.hero import ServeConfig
+
+    art = quantized_artifact(0)
+    assert [sum(h.is_direct(l) for l in range(h.n_levels))
+            for h in (CFG.field.hash,) + CFG.proposals] == [1, 0, 0]
+    monkeypatch.setattr(fr, "_slot_propose_impl", lambda pack, o, d, **kw: o)
+    monkeypatch.setattr(fr, "_slot_shade_impl",
+                        lambda pack, o, d, edges, **kw: jnp.zeros_like(o))
+    svc = hero.serve(art, ServeConfig(slots=2, slot_rays=64))
+    svc.render(*map(np.asarray, rays(n=150)))  # 3 slots
+    c = svc.stats()["trace"]["counters"]
+    per_slot = 64 * (16 * 2 * 8 + 8 * 2 * 8 + 8 * 4 * 8)
+    assert c["hash.lookups"] == 3 * per_slot
+    assert c["hash.cell_lookups"] == 3 * 64 * 8 * 1 * 8

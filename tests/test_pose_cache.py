@@ -355,4 +355,31 @@ def test_engine_without_budget_counts_no_samples(tiny_artifact):
     tr = eng.stats()["trace"]
     assert eng.budget is None
     assert tr["spans"]["render.wait"]["count"] == 3
-    assert tr["counters"] == {}
+    assert set(tr["counters"]) == {"hash.lookups", "hash.cell_lookups"}
+
+
+@pytest.mark.parametrize("budget", ["auto", None])
+def test_engine_counts_hash_lookups_per_march_slot(tiny_artifact, monkeypatch,
+                                                   budget):
+    """Zero-render: with the march program stubbed, each march slot counts
+    the corner lookups of its field query, 8 a level at each point the
+    field is queried at (the sample budget, or every sample without one),
+    and the part its one direct-indexed level serves from cell-major
+    rows."""
+    import jax.numpy as jnp
+
+    from repro.nerf import fast_render as fr
+
+    monkeypatch.setattr(
+        fr, "_slot_march_impl",
+        lambda params, pack, spec, occ, ro, rd, **kw: (
+            jnp.zeros_like(ro), jnp.int32(0)))
+    eng = _engine(tiny_artifact, budget=budget, pose_cache=False)
+    eng.reset_stats()
+    eng.render(*_orbit(0.7, 0.12), scene=tiny_artifact.scene)  # 3 slots
+    c = eng.stats()["trace"]["counters"]
+    hcfg = tiny_artifact.cfg.hash
+    assert [hcfg.is_direct(l) for l in range(4)] == [True, False, False, False]
+    points = eng.budget or 64 * tiny_artifact.rcfg.n_samples
+    assert c["hash.lookups"] == 3 * points * 4 * 8
+    assert c["hash.cell_lookups"] == 3 * points * 1 * 8

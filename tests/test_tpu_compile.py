@@ -115,6 +115,26 @@ def test_hash_encode_compiles_to_xla_gather_at_paper_widths(spec):
     assert "tpu_custom_call" not in text
 
 
+@pytest.mark.parametrize("field", ["paper", "proposal1", "proposal2"])
+def test_cell_major_hash_encode_compiles_at_published_widths(spec, field):
+    """The encode with the direct-indexed levels' cell-major table (levels
+    0-4 of the T=2^19 field, 0-2 and 0-1 of Nerfacto's proposal fields),
+    4,096 points: one XLA gather a staged table, no Mosaic kernel."""
+    cfg = (paper().hash if field == "paper"
+           else nerfacto().proposals[int(field[-1]) - 1])
+    L, F = cfg.n_levels, cfg.n_features
+    rows = tuple(cfg.level_entries(l) for l in range(L))
+    direct = tuple(cfg.is_direct(l) for l in range(L))
+    cell_rows = sum(r for r, d in zip(rows, direct) if d)
+    text = _compiled_text(
+        lambda i, w, t, c: hash_encode(i, w, t, rows, c, direct),
+        spec((L, POINTS, 8), jnp.int32), spec((L, POINTS, 8), jnp.float32),
+        spec((sum(rows), F), jnp.float32), spec((cell_rows, 8 * F), jnp.float32),
+    )
+    assert text.count(" gather(") == 2
+    assert "tpu_custom_call" not in text
+
+
 def test_ray_march_compiles_at_g32(spec):
     br, bs, bt = RAY_MARCH_DEFAULT
     text = _compiled_text(
