@@ -74,12 +74,6 @@ register(
     "cache behavior (BENCH_serve.json 'burst' key)",
 )
 register(
-    "pose_stream", "benchmarks.pose_stream", "main",
-    "ad-hoc fresh-pose serve stream: pose-cache tiers vs the legacy "
-    "scatter path + warm-hit CullPlan overhead (BENCH_serve.json "
-    "'pose_stream' key)",
-)
-register(
     "artifact_size", "benchmarks.artifact_size", "main",
     "packed-artifact bytes by policy + codec throughput + roundtrip PSNR "
     "parity gates (BENCH_artifact.json)",

@@ -13,10 +13,10 @@ tile-native and served through `ServeEngine`: full test-view frames and
 one fresh pose that takes the `march` tier. Checks: served PSNR equals
 the compile-time fused PSNR (1e-3 dB), the fused integer path agrees with
 the fake-quant float reference (0.1 dB), the fresh pose served through
-the march tier agrees with the same rays served by an engine with
-`compaction="scatter"`, the march kernel's mask on those rays equals
-`ref.ray_march_ref`, and one `quant_matmul_packed` call equals its
-reference bit for bit.
+the march tier agrees with the same rays rendered in one chunk by the
+artifact's fused `FastRenderEngine`, the march kernel's mask on those
+rays equals `ref.ray_march_ref`, and one `quant_matmul_packed` call
+equals its reference bit for bit.
 
 Four chips: the same scene bundle's population is scored through
 `shard_population` on a 4-device ("pop",) mesh and on a 1-device mesh;
@@ -52,11 +52,11 @@ ITERATIONS, POPULATION = 2, 8  # search depth cuts
 SLOTS, SLOT_RAYS = 4, 1024
 SERVE_BAND_DB = 1e-3  # served vs compile-time fused PSNR
 REFERENCE_BAND_DB = 0.1  # fused integer path vs fake-quant reference
-# March tier vs scatter engine, per color channel in [0, 1]: float
-# rounding between two compiled programs (8 ulp at 1.0), far below one
-# 8-bit color level; a sample the march mask dropped or added moves a
+# March tier vs the one-chunk fused render, per color channel in [0, 1]:
+# float rounding between two compiled programs (8 ulp at 1.0), far below
+# one 8-bit color level; a sample the march mask dropped or added moves a
 # pixel by that sample's whole contribution.
-MARCH_VS_SCATTER_ATOL = 1e-6
+MARCH_VS_FUSED_ATOL = 1e-6
 SHARD_CYCLES_RTOL = 1e-3
 SHARD_QUALITY_DB = 1e-4
 
@@ -229,20 +229,19 @@ def phase_serve(loaded, parity: dict):
           f"fresh pose served through the march tier ({n_items} slots)")
     check(bool(np.isfinite(colors).all()), "fresh-pose frame finite")
 
-    # The served march tier against the legacy cumsum+scatter compaction
-    # on the same rays and slots: a fault in the march mask, its gather
-    # compaction or the slot assembly moves the served colors.
-    scatter = hero.serve({SCENE: loaded}, ServeConfig(
-        slots=SLOTS, slot_rays=SLOT_RAYS).engine_config(compaction="scatter"))
-    want = scatter.render(ro, rd, scene=SCENE)
+    # The served march tier against the fused render of the same rays as
+    # one chunk, under the exact budget of those rays: a fault in the
+    # slot assembly or the budget growth moves the served colors.
+    want = np.asarray(loaded.engine(mode="fused").render_rays(ro, rd))
     diff = np.abs(colors - want)
-    parity["march_vs_scatter_max_abs"] = float(diff.max())
-    parity["march_vs_scatter_byte_equal"] = bool(np.array_equal(colors, want))
-    print(f"[smoke] fresh pose: march tier vs scatter engine max |diff| "
-          f"{diff.max():.3e}, byte-equal {parity['march_vs_scatter_byte_equal']}")
-    check(diff.max() <= MARCH_VS_SCATTER_ATOL,
-          f"served march tier == scatter engine within "
-          f"{MARCH_VS_SCATTER_ATOL} per channel")
+    parity["march_vs_fused_max_abs"] = float(diff.max())
+    parity["march_vs_fused_byte_equal"] = bool(np.array_equal(colors, want))
+    print(f"[smoke] fresh pose: march tier vs one-chunk fused render max "
+          f"|diff| {diff.max():.3e}, byte-equal "
+          f"{parity['march_vs_fused_byte_equal']}")
+    check(diff.max() <= MARCH_VS_FUSED_ATOL,
+          f"served march tier == one-chunk fused render within "
+          f"{MARCH_VS_FUSED_ATOL} per channel")
     ref_colors = np.asarray(
         loaded.engine(mode="reference").render_rays(ro, rd)
     )

@@ -46,7 +46,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.repack import DEFAULT_TILE_BK, unrepack_planar
+from repro.kernels.repack import unrepack_planar
 from repro.nerf import nerfacto
 from repro.nerf.fast_render import (
     FastRenderEngine,
@@ -261,17 +261,16 @@ class QuantArtifact:
         return path
 
     @staticmethod
-    def load(path, layout: str = f"tile:{DEFAULT_TILE_BK}") -> "QuantArtifact":
+    def load(path) -> "QuantArtifact":
         """Load a saved bundle. Integrity (array-set match + per-array
         sha256 against the directory's OWN manifest) is verified for every
         schema version before any reconstruction; a v1 directory is then
         auto-upgraded in memory (module docstring).
 
-        `layout` picks the compute repack staged after verification (the
+        After verification each field's compute forms are staged (the
         one-time tile-native permutation + fused-encode staging of
-        `repack_fused_pack`); pass `"planar"` to serve the bare
-        schema-v2 storage form unmodified (slower hot path, identical
-        numerics). Stored bytes are the same either way."""
+        `repack_fused_pack`, at its default tile), as a compile
+        stages them; the stored bytes are untouched."""
         path = Path(path)
         manifest = json.loads((path / "manifest.json").read_text())
         version = int(manifest.get("schema_version", -1))
@@ -363,7 +362,7 @@ class QuantArtifact:
             units = make_quant_units(cfg)
             policy = QuantPolicy.uniform(units, 8).with_bits(bits)
             spec = spec_from_policy(cfg, policy, act_ranges)
-            pack = build_fused_pack(params, cfg, spec, layout=layout)
+            pack = build_fused_pack(params, cfg, spec)
             metrics["model_bytes"] = float(fused_pack_stored_bytes(pack))
         else:
             modes = manifest["pack_modes"]
@@ -375,12 +374,10 @@ class QuantArtifact:
                     f"prop{k + 1}": h for k, h in enumerate(cfg.proposals)}}
             fields = {}
             for field, m in modes.items():
-                fields[field] = FusedPack(
+                fields[field] = repack_fused_pack(FusedPack(
                     layers=layers[field], hash_tables=tables[field],
                     modes=tuple(m), hash_cfg=hcfgs[field],
-                )
-                if layout != "planar":
-                    fields[field] = repack_fused_pack(fields[field], layout)
+                ))
             pack = fields.pop("")
             if model == "nerfacto":
                 pack = NerfactoPack(

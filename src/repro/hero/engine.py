@@ -4,11 +4,11 @@ The shape of an LLM inference engine, specialized to NeRF rays:
 
   submit -> per-scene FIFO queues -> [Scheduler] -> single-scene bucket
          -> [ArtifactCache: LRU load-on-miss, byte-budgeted eviction]
-         -> device step (one jitted call, fixed padded shapes)
+         -> device step (jitted calls a slot, fixed padded shapes)
          -> scatter into request buffers -> poll()/result() streaming
 
 Every `step()` admits up to `slots` queued work items of ONE scene (the
-scheduler's oldest-first bucket), renders them in one device call at the
+scheduler's oldest-first bucket), renders them slot by slot at the
 engine's fixed `(slots, slot_rays, 3)` padded shape, and scatters the
 colors back. Multiple artifacts are resident at once; because the padded
 bucket shape is a property of the ENGINE (not the artifact) and jax
@@ -24,7 +24,8 @@ renders, and they are the design constraint on this layer:
     `time.perf_counter`. All timestamps (submit, done, latency stats)
     come from it, so a fake counter makes timing assertions exact.
   * `device_step=` — `(scene, artifact, ro, rd) -> (S, R, 3) colors`;
-    defaults to `FusedDeviceStep` (the real fused integer render with
+    defaults to `FusedDeviceStep`, whose `step_items` renders each slot
+    through the real fused integer render (a pose-cache tier with
     grow-on-overflow sample budgets, or for a proposal-sampled Nerfacto
     artifact its two programs a slot at fixed samples). A scripted fake
     turns `step()` into a pure state transition.
@@ -185,7 +186,8 @@ class ArtifactCache:
 # Default device step: the real fused integer render
 # ---------------------------------------------------------------------------
 class FusedDeviceStep:
-    """`(scene, artifact, ro, rd) -> colors` through the fused render path.
+    """`step_items(scene, artifact, items, ro, rd) -> colors` through the
+    fused render path, one slot at a time.
 
     Per-scene state (quant spec, eval rcfg, grow-on-overflow sample
     budget) lives HERE, not in the cache entry: a scene's budget survives
@@ -206,10 +208,9 @@ class FusedDeviceStep:
         self.obs = recorder  # the owning engine's spans and counters
         self._align = 128
         self._state: Dict[str, Dict] = {}
-        assert cfg.compaction in ("march", "scatter"), cfg.compaction
         self._pose_cache = None
         self._pose_grid = None
-        if cfg.pose_cache and cfg.compaction == "march":
+        if cfg.pose_cache:
             from repro.nerf.pose_cache import PoseGridConfig, PosePlanCache
 
             self._pose_grid = PoseGridConfig(
@@ -252,37 +253,7 @@ class FusedDeviceStep:
         return st
 
     # ------------------------------------------------------------------
-    def __call__(self, scene: str, artifact, ro: np.ndarray, rd: np.ndarray):
-        import jax.numpy as jnp
-
-        from repro.nerf.fast_render import _frame_colors_impl
-        from repro.nerf.occupancy import sample_active_mask
-
-        st = self._scene_state(scene, artifact)
-        if st["budget"] is not None:
-            # Exactness guard: grow the static budget (one retrace) before
-            # a step could overflow and silently drop samples.
-            active, _ = sample_active_mask(artifact.occ, ro, rd, st["rcfg"])
-            need = int(active.reshape(ro.shape[0], -1).sum(axis=1).max())
-            if need > st["budget"]:
-                grown = int(
-                    np.ceil(need * self.cfg.budget_headroom / self._align)
-                    * self._align
-                )
-                st["budget"] = min(
-                    grown, self.cfg.slot_rays * st["rcfg"].n_samples
-                )
-                st["retraces"] += 1
-        return np.asarray(_frame_colors_impl(
-            artifact.params, artifact.pack, st["spec"], artifact.occ,
-            jnp.asarray(ro), jnp.asarray(rd),
-            cfg=artifact.cfg, rcfg=st["rcfg"], mode="fused",
-            budget=st["budget"], use_pallas=self.cfg.use_pallas,
-            early_stop=self.cfg.early_stop, compaction=self.cfg.compaction,
-        ))
-
-    # ------------------------------------------------------------------
-    # Pose-cache tiers (the `step_items` serve fast path)
+    # Pose-cache tiers of `step_items`
     # ------------------------------------------------------------------
     def pose_key(self, scene: str, ro: np.ndarray, rd: np.ndarray,
                  artifact=None):
@@ -504,9 +475,6 @@ class FusedDeviceStep:
                 colors[slot] = self._propose_slot(artifact, it, ro[slot],
                                                   rd[slot])
             return colors
-        if self.cfg.compaction != "march":
-            # Legacy scatter strategy has no tiers: one padded-bucket call.
-            return np.asarray(self(scene, artifact, ro, rd))
         st = self._scene_state(scene, artifact)
         S = ro.shape[0]
         colors = np.zeros((S, ro.shape[1], 3), np.float32)
@@ -552,7 +520,7 @@ class ServeEngine:
         self._stepper = (
             FusedDeviceStep(cfg, self.obs) if device_step is None else None
         )
-        self._device_step = device_step if device_step is not None else self._stepper
+        self._device_step = device_step
         self._sched = Scheduler(cfg.slots)
         self._events = (
             deque(maxlen=cfg.trace_events) if cfg.trace_events > 0 else None
@@ -779,12 +747,12 @@ class ServeEngine:
                     rd[slot, :n] = it.rays_d
             sp.set(scene=scene, items=len(items))
 
-            # The fused stepper's item-aware entry routes each slot through
-            # the pose-cache tiers (hit/warp/march); injected 4-arg fakes
-            # keep the plain padded-bucket protocol.
-            step_items = getattr(self._device_step, "step_items", None)
-            if step_items is not None:
-                colors = np.asarray(step_items(scene, entry.artifact, items, ro, rd))
+            # The fused stepper renders item by item (each slot through its
+            # pose-cache tier or its proposal programs); injected 4-arg
+            # fakes keep the plain padded-bucket protocol.
+            if self._stepper is not None:
+                colors = np.asarray(self._stepper.step_items(
+                    scene, entry.artifact, items, ro, rd))
             else:
                 colors = np.asarray(self._device_step(scene, entry.artifact, ro, rd))
             assert colors.shape == (S, R, 3), colors.shape

@@ -78,12 +78,6 @@ class EngineConfig:
     # submit() that would exceed it raises AdmissionFull (and counts in
     # the `rejected` stat). None = unbounded (the historical behavior).
     max_pending: Optional[int] = None
-    # Ad-hoc compaction strategy of the fused stepper: "march" (default;
-    # the Pallas occupancy ray-march active mask + gather compaction) or
-    # "scatter" (the legacy cumsum+scatter path — byte-identical colors,
-    # kept as the benchmark baseline and an escape hatch). "scatter"
-    # disables the pose-cache tiers.
-    compaction: str = "march"
     # Pose-grid plan cache (`repro.nerf.pose_cache`): ad-hoc requests are
     # keyed to a quantized pose cell; repeat cells get compiled cull
     # plans (hit tier) and nearby poses reuse them conservatively (warp

@@ -12,7 +12,8 @@ batched env's PSNR proxy — rebuilt around three ideas:
    `CullPlan` — pure gather indices, no cumsum/scatter in the hot path,
    and an EXACT per-chunk budget (the active mask depends only on
    geometry and the frozen grid, never on params or the policy). Ad-hoc
-   rays fall back to an on-device cumsum compaction.
+   rays take the active mask from the occupancy ray-march kernel and
+   compact on device with a `nonzero` gather.
 2. **Real integer inference** (`mode="fused"`): a `FusedPack` precomputes
    sub-byte PACKED weight codes per linear layer and packed integer
    hash-table codes (`repro.quant.packing.PackedTensor` — b-bit payloads
@@ -63,7 +64,6 @@ from repro.kernels.ops import (
     alpha_composite as ops_alpha_composite,
     fused_field_query as ops_fused_field_query,
     hash_encode as ops_hash_encode,
-    hash_gather as ops_hash_gather,
     quant_matmul_packed as ops_quant_matmul_packed,
     ray_march as ops_ray_march,
 )
@@ -125,9 +125,12 @@ class FusedPack:
     `repack_fused_pack` (`layout` records which repack): tile-native
     packed words per layer, the concatenated dequantized hash table and
     the direct-indexed levels' cell-major table for the fused encode,
-    float weight carriers. Dropping `compute` loses speed, never data.
-    `hash_cfg` is the tables' encoding (which levels are direct-indexed,
-    at what resolution); a pack without it stages no cell-major table.
+    float weight carriers. The field query reads only those staged
+    forms: a `"planar"` pack (empty `compute`) is a storage form, which
+    is serialized and measured but never served — `repack_fused_pack`
+    stages it first. `hash_cfg` is the tables' encoding (which levels
+    are direct-indexed, at what resolution); a pack without it stages no
+    cell-major table.
     """
 
     layers: Dict[str, Dict[str, jnp.ndarray]]
@@ -169,8 +172,8 @@ def build_fused_pack(
 
     `layout` selects the staged compute representation
     (`repack_fused_pack`): the default tile-native repack + fused-encode
-    staging, or `"planar"` for the bare storage-only pack (schema-v2
-    compatibility; identical numerics, slower hot path).
+    staging, or `"planar"` for the bare storage-only pack, which is not
+    served until `repack_fused_pack` stages it.
 
     Requires a CONCRETE spec (host floats, not tracers): the bit widths
     pick the lowering per layer at build time, and the packing windows
@@ -352,19 +355,16 @@ def _use_kernels(use_pallas) -> bool:
 
 
 def _layer_wq(pack: FusedPack, name: str) -> PackedTensor:
-    """The kernel-facing packed weight: the staged tile-native repack
-    when present, the storage-planar words otherwise."""
-    return pack.compute.get(f"{name}::wq_tile", pack.layers[name]["wq"])
+    """The kernel-facing packed weight: the staged tile-native repack."""
+    return pack.compute[f"{name}::wq_tile"]
 
 
 def _fused_weight_f32(pack: FusedPack, name: str) -> jnp.ndarray:
     """The layer's float-carrier weight: the staged dequantized carrier
-    when present, dequantized packed codes when the storage is sub-byte,
-    the stored f32 carrier otherwise."""
+    of sub-byte codes, the stored f32 carrier otherwise."""
     lyr = pack.layers[name]
     if "wq" in lyr:
-        staged = pack.compute.get(f"{name}::w_f32")
-        return lyr["wq"].dequantize() if staged is None else staged
+        return pack.compute[f"{name}::w_f32"]
     return lyr["w"]
 
 
@@ -399,52 +399,31 @@ def _fused_first_linear(pack: FusedPack, points, hcfg, name: str,
     """Hash encode of `points` (P, 3) in [0, 1] over the pack's tables,
     then its first linear `name` (mode 0): the pre-activation (P, N).
 
-    With a repacked pack (`pack.compute` staged) the encode is the fused
-    `ops.hash_encode` over the staged tables — the concatenated table for
-    hashed levels, the cell-major one for direct-indexed levels where it
-    is staged — which keeps per-level `dequantize()` out of the jitted
-    hot path, where XLA:CPU fuses it into every gather lane; on the
-    kernel path the linear folds into `ops.fused_field_query`."""
+    The encode is the fused `ops.hash_encode` over the tables
+    `repack_fused_pack` staged — the concatenated table for hashed
+    levels, the cell-major one for direct-indexed levels where it is
+    staged — which keeps per-level `dequantize()` out of the jitted hot
+    path, where XLA:CPU fuses it into every gather lane; on the kernel
+    path the linear folds into `ops.fused_field_query`."""
     L = hcfg.n_levels
-    if "table_cat" in pack.compute:
-        if corner_data is None:
-            per_level = [level_corner_data(points, l, hcfg)
-                         for l in range(L)]
-            idx = jnp.stack([i for i, _ in per_level])  # (L, P, 8)
-            w = jnp.stack([w_ for _, w_ in per_level])
-        else:
-            idx, w = corner_data
-        cat = pack.compute["table_cat"]
-        rows = tuple(hcfg.level_entries(l) for l in range(L))
-        cells = pack.compute.get("cells_cat")
-        direct = (() if cells is None
-                  else tuple(hcfg.is_direct(l) for l in range(L)))
-        if pack.modes[0] == "int" and _use_kernels(use_pallas):
-            lyr = pack.layers[name]
-            return ops_fused_field_query(
-                idx, w, cat, rows, _layer_wq(pack, name), lyr,
-                use_pallas=use_pallas, cells_cat=cells, direct=direct,
-            ) + lyr["b"]
-        enc = ops_hash_encode(idx, w, cat, rows, cells, direct)
-        return _fused_linear(pack, 0, name, enc, use_pallas)
-    # Storage-only pack (schema-v2 artifact loaded without repack):
-    # per-level gathers over tables dequantized inside the call.
-    feats = []
-    for l in range(L):
-        if corner_data is None:
-            idx, w = level_corner_data(points, l, hcfg)  # (P, 8)
-        else:
-            idx, w = corner_data[0][l], corner_data[1][l]
-        table = pack.hash_tables[f"level_{l}"]
-        if isinstance(table, PackedTensor):
-            # Stored form is integer codes in packed words; the gather
-            # runs over the dequantized grid (codes * scale), expanded
-            # inside the jitted call — DRAM holds the packed bytes.
-            table = table.dequantize()
-        vals = ops_hash_gather(idx.reshape(-1), table).reshape(
-            idx.shape + (hcfg.n_features,))
-        feats.append(jnp.sum(vals * w[..., None], axis=1))
-    enc = jnp.concatenate(feats, axis=-1)
+    if corner_data is None:
+        per_level = [level_corner_data(points, l, hcfg) for l in range(L)]
+        idx = jnp.stack([i for i, _ in per_level])  # (L, P, 8)
+        w = jnp.stack([w_ for _, w_ in per_level])
+    else:
+        idx, w = corner_data
+    cat = pack.compute["table_cat"]
+    rows = tuple(hcfg.level_entries(l) for l in range(L))
+    cells = pack.compute.get("cells_cat")
+    direct = (() if cells is None
+              else tuple(hcfg.is_direct(l) for l in range(L)))
+    if pack.modes[0] == "int" and _use_kernels(use_pallas):
+        lyr = pack.layers[name]
+        return ops_fused_field_query(
+            idx, w, cat, rows, _layer_wq(pack, name), lyr,
+            use_pallas=use_pallas, cells_cat=cells, direct=direct,
+        ) + lyr["b"]
+    enc = ops_hash_encode(idx, w, cat, rows, cells, direct)
     return _fused_linear(pack, 0, name, enc, use_pallas)
 
 
@@ -596,14 +575,13 @@ def build_cull_plan(
 def _chunk_color(
     params, pack, spec, occ, rays_o, rays_d,
     cfg, rcfg, mode, budget, use_pallas, early_stop,
-    key=None, plan_row=None, compaction="march",
+    key=None, plan_row=None,
 ):
     """Core renderer for one chunk of rays. Returns (color (R,3), acc (R,1)).
 
-    `compaction` picks the ad-hoc-ray strategy: "march" (default) gets the
-    active mask from the occupancy ray-march kernel and compacts with a
-    `nonzero`-gather; "scatter" is the legacy cumsum+scatter path, kept as
-    the benchmark baseline and the byte-identity pin for "march".
+    Ad-hoc rays get their active mask from the occupancy ray-march kernel
+    and are compacted with a `nonzero` gather; `plan_row` replaces both
+    with a host-baked plan's gathers.
     """
     n_rays = rays_o.shape[0]
     n_s = rcfg.n_samples
@@ -650,7 +628,7 @@ def _chunk_color(
             # The march kernel and the inline lookup agree bit-exactly
             # (`ref.ray_march_ref` IS this expression); stratified sampling
             # perturbs t per ray, which the (S,)-t kernel cannot see.
-            if compaction == "scatter" or (rcfg.stratified and key is not None):
+            if rcfg.stratified and key is not None:
                 active = inside.reshape(-1) & occupancy_lookup(occ, flat_pts)
             else:
                 active = ops_ray_march(
@@ -660,17 +638,12 @@ def _chunk_color(
             B = P if budget is None else min(int(budget), P)
             rank = jnp.cumsum(active) - 1  # (P,) int
             valid = active & (rank < B)  # budget overflow drops samples
-            if compaction == "march":
-                # Gather compaction: nonzero returns the active flat
-                # indices in increasing order — the same rank order the
-                # scatter writes, so the buffers are byte-identical.
-                (inv_take,) = jnp.nonzero(valid, size=B, fill_value=0)
-                buf_pts = flat_pts[inv_take]
-                buf_dirs = flat_dirs[inv_take]
-            else:
-                pos = jnp.where(valid, rank, B)  # B = out of range -> dropped
-                buf_pts = jnp.zeros((B, 3)).at[pos].set(flat_pts, mode="drop")
-                buf_dirs = jnp.zeros((B, 3)).at[pos].set(flat_dirs, mode="drop")
+            # Gather compaction: nonzero returns the active flat indices
+            # in increasing order, so buffer row `rank` holds the sample
+            # of that rank.
+            (inv_take,) = jnp.nonzero(valid, size=B, fill_value=0)
+            buf_pts = flat_pts[inv_take]
+            buf_dirs = flat_dirs[inv_take]
             sigma_b, rgb_b = field(buf_pts, buf_dirs)
             take = jnp.clip(rank, 0, B - 1)
             sigma = jnp.where(valid, sigma_b[take], 0.0).reshape(n_rays, n_s)
@@ -825,22 +798,19 @@ def _frame_se_impl(
 @functools.partial(
     jax.jit,
     static_argnames=("cfg", "rcfg", "mode", "budget", "use_pallas",
-                     "early_stop", "compaction"),
+                     "early_stop"),
 )
 def _frame_colors_impl(
     params, pack, spec, occ, rays_o, rays_d,
-    *, cfg, rcfg, mode, budget, use_pallas, early_stop, compaction="march",
+    *, cfg, rcfg, mode, budget, use_pallas, early_stop,
 ):
     # Image rendering takes arbitrary rays (no precomputed plan): the
     # dynamic compaction path under `budget` applies per chunk.
-    # `compaction="scatter"` keeps the legacy cumsum+scatter strategy (the
-    # pose-stream benchmark's baseline; byte-identical to "march").
     def body(xs):
         ro, rd = xs
         color, _ = _chunk_color(
             params, pack, spec, occ, ro, rd,
             cfg, rcfg, mode, budget, use_pallas, early_stop,
-            compaction=compaction,
         )
         return color
     return jax.lax.map(body, (rays_o, rays_d))

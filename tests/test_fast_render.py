@@ -187,13 +187,12 @@ def test_plan_compaction_byte_identical_to_cumsum_fallback(params, rays):
     reconstruction are byte-identical (assert_array_equal, no
     tolerance). Every path stages its sample depths from the one
     host-side `ray_t_samples` source (the old np-vs-jnp linspace ulp is
-    gone), so END-TO-END COLORS are byte-equal too: between the two
-    dynamic strategies (march vs the legacy cumsum+scatter) in every
-    mode, and between the plan path and the dynamic paths in the fused
-    integer mode the engine serves (activation quantization rounds away
-    the one remaining divergence — XLA fuses the in-graph `ro + rd*t`
-    into FMAs the host baker cannot reproduce, a 1-ulp float residue
-    pinned by the reference-mode allclose below)."""
+    gone), so END-TO-END COLORS are byte-equal too: between the plan
+    path and the dynamic path in the fused integer mode the engine
+    serves (activation quantization rounds away the one remaining
+    divergence — XLA fuses the in-graph `ro + rd*t` into FMAs the host
+    baker cannot reproduce, a 1-ulp float residue pinned by the
+    reference-mode allclose below)."""
     ro, rd = rays
     rng = np.random.RandomState(7)
     occ = OccupancyGrid(
@@ -205,8 +204,9 @@ def test_plan_compaction_byte_identical_to_cumsum_fallback(params, rays):
     )
     B = plan.budget
 
-    # The fallback's compaction (the occ branch of the chunk renderer),
-    # replayed over the same host-staged samples the plan was built from.
+    # The dynamic compaction's buffers (the occ branch of the chunk
+    # renderer), replayed as an independent cumsum+scatter over the same
+    # host-staged samples the plan was built from.
     active, pts = sample_active_mask(occ, np.asarray(ro), np.asarray(rd),
                                      RCFG)
     flat_active = jnp.asarray(active.reshape(-1))
@@ -237,38 +237,32 @@ def test_plan_compaction_byte_identical_to_cumsum_fallback(params, rays):
     rec_dyn = jnp.where(valid, vals[take], 0.0)
     np.testing.assert_array_equal(np.asarray(rec_plan), np.asarray(rec_dyn))
 
-    # End-to-end, reference mode: the two dynamic strategies are the
-    # same device graph modulo compaction -> bit-equal; the host-baked
-    # plan is 1-ulp off (in-graph FMA), pinned at float roundoff.
+    # End-to-end, reference mode: the host-baked plan is 1-ulp off the
+    # dynamic path (in-graph FMA), pinned at float roundoff.
     from repro.nerf.fast_render import _frame_colors_impl
 
-    def dyn(strategy, pack=None, spec=None, mode="reference"):
+    def dyn(pack=None, spec=None, mode="reference"):
         return np.asarray(_frame_colors_impl(
             params, pack, spec, occ, jnp.asarray(ro)[None],
             jnp.asarray(rd)[None], cfg=CFG, rcfg=RCFG, mode=mode,
             budget=B, use_pallas="auto", early_stop=True,
-            compaction=strategy,
         )[0])
 
     want_ref, _ = fast_render_rays(
         params, ro, rd, CFG, RCFG, None, occ=occ, mode="reference", plan=plan,
     )
-    np.testing.assert_array_equal(dyn("march"), dyn("scatter"))
-    np.testing.assert_allclose(dyn("march"), np.asarray(want_ref), atol=1e-6)
+    np.testing.assert_allclose(dyn(), np.asarray(want_ref), atol=1e-6)
 
     # End-to-end, fused integer mode (what the serve engine runs): the
-    # quantizer absorbs the FMA ulp -> plan == march == scatter, bitwise.
+    # quantizer absorbs the FMA ulp -> plan == dynamic, bitwise.
     spec = SPECS["uniform8"](params)
     pack = build_fused_pack(params, CFG, spec)
     want_fused, _ = fast_render_rays(
         params, ro, rd, CFG, RCFG, spec, occ=occ, mode="fused", pack=pack,
         plan=plan,
     )
-    got_march = dyn("march", pack=pack, spec=spec, mode="fused")
-    np.testing.assert_array_equal(got_march, np.asarray(want_fused))
-    np.testing.assert_array_equal(
-        got_march, dyn("scatter", pack=pack, spec=spec, mode="fused")
-    )
+    got = dyn(pack=pack, spec=spec, mode="fused")
+    np.testing.assert_array_equal(got, np.asarray(want_fused))
 
 
 def test_empty_grid_renders_background(params, rays):

@@ -322,23 +322,38 @@ def test_load_restages_the_cell_major_table(tiny_artifact, tmp_path):
                                   np.asarray(want))
     assert fused_pack_stored_bytes(loaded.pack) == \
         fused_pack_stored_bytes(tiny_artifact.pack)
-    planar = hero.QuantArtifact.load(tmp_path / "a", layout="planar")
-    assert "cells_cat" not in planar.pack.compute
 
 
-def test_planar_layout_load_serves_identically(tiny_env, tiny_artifact,
-                                               tmp_path):
-    """layout="planar" opts out of the compile-time repack (storage-only
-    pack, no staged compute) and still serves the same numbers."""
-    path = tiny_artifact.save(tmp_path / "art")
-    tile = hero.QuantArtifact.load(path)
-    planar = hero.QuantArtifact.load(path, layout="planar")
-    assert planar.pack.layout == "planar"
-    assert not planar.pack.compute
+def test_load_stages_every_compute_form_and_serves_identically(
+        tiny_env, tiny_artifact, tmp_path):
+    """A loaded artifact holds every compute form the fused field query
+    reads — the concatenated table, the cell-major table where a level
+    is direct-indexed, and the tile-native words and float carrier of
+    each packed layer — equal to the compiled artifact's, and evaluates
+    the same PSNR."""
+    from repro.kernels.repack import DEFAULT_TILE_BK
+    from repro.quant.packing import PackedTensor
+
+    loaded = hero.QuantArtifact.load(tiny_artifact.save(tmp_path / "art"))
+    pack, hcfg = loaded.pack, loaded.cfg.hash
+    assert pack.layout == f"tile:{DEFAULT_TILE_BK}"
+    want = {"table_cat"}
+    if any(hcfg.is_direct(l) for l in range(hcfg.n_levels)):
+        want.add("cells_cat")
+    packed = [n for n, lyr in pack.layers.items() if "wq" in lyr]
+    assert packed
+    for name in packed:
+        want |= {f"{name}::wq_tile", f"{name}::w_f32"}
+    assert set(pack.compute) == want
+    for k, v in tiny_artifact.pack.compute.items():
+        got = pack.compute[k]
+        if isinstance(v, PackedTensor):
+            assert got.layout == v.layout
+            v, got = v.words, got.words
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(v))
     ds = tiny_env.dataset
-    assert tile.engine().evaluate_psnr(ds) == pytest.approx(
-        planar.engine().evaluate_psnr(ds), abs=1e-6
-    )
+    assert loaded.engine().evaluate_psnr(ds) == \
+        tiny_artifact.engine().evaluate_psnr(ds)
 
 
 def test_artifact_integrity_check_fails_loudly(tiny_artifact, tmp_path):

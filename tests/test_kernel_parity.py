@@ -327,15 +327,13 @@ def test_hash_encode_bit_identical_at_bench_level_sizes(config, F):
     rng = np.random.RandomState(F)
     lin = {"w": jnp.asarray(rng.standard_normal((L * F, 16)), jnp.float32),
            "b": jnp.asarray(rng.standard_normal(16), jnp.float32)}
-    planar = fr.FusedPack(
+    staged = fr.repack_fused_pack(fr.FusedPack(
         layers={"sigma/0": lin},
         hash_tables={f"level_{l}": t for l, t in enumerate(tables)},
-        modes=("float",), hash_cfg=hcfg)
-    staged = fr.repack_fused_pack(planar)
+        modes=("float",), hash_cfg=hcfg))
     np.testing.assert_array_equal(np.asarray(staged.compute["cells_cat"]),
                                   np.asarray(cells))
-    want = fr._fused_first_linear(planar, pts, hcfg, "sigma/0", False,
-                                  corner_data=(idx, w))
+    want = _per_level_take(tables, idx, w) @ lin["w"] + lin["b"]
     for corner_data in ((idx, w), None):
         got = fr._fused_first_linear(staged, pts, hcfg, "sigma/0", False,
                                      corner_data=corner_data)

@@ -5,7 +5,7 @@ The load-bearing property (pinned here both host-side and end-to-end):
 a plan built with coverage margin `m` never culls a sample that the
 exact plan of ANY rays within `m` L-inf deviation would keep — so the
 warp tier's colors are byte-identical to the march tier's, and every
-tier sits inside the 1e-3 dB PSNR band of the legacy scatter path.
+tier sits inside the 1e-3 dB PSNR band of an engine without the cache.
 """
 import numpy as np
 import pytest
@@ -244,11 +244,12 @@ def _psnr(a, b):
 
 def test_engine_tier_progression_and_parity(tiny_artifact):
     """One pose revisited: miss -> miss+build -> hit; in-cell jitter ->
-    warp. March colors are byte-identical to the scatter engine's, warp
-    colors byte-identical to the hit tier's, PSNR deltas pinned 0."""
+    warp. Every tier's colors are byte-identical to an engine without the
+    pose cache (its every slot marches, with no plans) on the same rays,
+    PSNR deltas pinned 0."""
     scene = tiny_artifact.scene
     eng = _engine(tiny_artifact)
-    eng_scatter = _engine(tiny_artifact, compaction="scatter")
+    eng_plain = _engine(tiny_artifact, pose_cache=False)
     stepper = eng._stepper
     # Height 0.11 sits mid-cell (pos_cell 0.05): jitter can't straddle.
     ro, rd = _orbit(0.3, 0.11)
@@ -257,7 +258,7 @@ def test_engine_tier_progression_and_parity(tiny_artifact):
     s1 = dict(stepper.pose_stats())
     assert s1["misses"] == 3 and s1["builds"] == 0 and s1["cells"] == 1
 
-    ref = eng_scatter.render(ro, rd, scene=scene)
+    ref = eng_plain.render(ro, rd, scene=scene)
     np.testing.assert_array_equal(march, ref)
 
     again = eng.render(ro, rd, scene=scene)  # visit 2: miss + build
@@ -286,7 +287,7 @@ def test_engine_tier_progression_and_parity(tiny_artifact):
         break
     assert warped is not None, "no jitter landed in the warp tier"
     ro_j, warp = warped
-    ref_j = eng_scatter.render(ro_j, rd, scene=scene)
+    ref_j = eng_plain.render(ro_j, rd, scene=scene)
     np.testing.assert_array_equal(warp, ref_j)
     assert abs(_psnr(warp, ref) - _psnr(ref_j, ref)) <= 1e-3  # dB band
 
@@ -304,12 +305,11 @@ def test_engine_plan_bytes_charged_to_resident(tiny_artifact):
     assert st["cache"]["resident_bytes"] == base + plan_bytes
 
 
-def test_engine_pose_cache_off_and_scatter_disable_tiers(tiny_artifact):
+def test_engine_pose_cache_off_disables_tiers(tiny_artifact):
     ro, rd = _orbit(2.0, 0.21)
-    for over in ({"pose_cache": False}, {"compaction": "scatter"}):
-        eng = _engine(tiny_artifact, **over)
-        eng.render(ro, rd, scene=tiny_artifact.scene)
-        assert eng.stats()["pose_cache"] is None
+    eng = _engine(tiny_artifact, pose_cache=False)
+    eng.render(ro, rd, scene=tiny_artifact.scene)
+    assert eng.stats()["pose_cache"] is None
 
 
 def test_engine_fresh_poses_build_nothing(tiny_artifact):
