@@ -8,13 +8,13 @@ The shape of an LLM inference engine, specialized to NeRF rays:
          -> scatter into request buffers -> poll()/result() streaming
 
 Every `step()` admits up to `slots` queued work items of ONE scene (the
-scheduler's oldest-first bucket), renders them slot by slot at the
-engine's fixed `(slots, slot_rays, 3)` padded shape, and scatters the
-colors back. Multiple artifacts are resident at once; because the padded
-bucket shape is a property of the ENGINE (not the artifact) and jax
-caches traces per static configuration, alternating scenes step after
-step re-uses each artifact's already-compiled trace — mixing scenes
-never retraces. Completed work items surface through `poll()` before the
+scheduler's oldest-first bucket), dispatches every slot's program at the
+engine's fixed `(slots, slot_rays, 3)` padded shape, fetches all their
+colors in one transfer, and scatters them back. Multiple artifacts are
+resident at once; because the padded bucket shape is a property of the
+ENGINE (not the artifact) and jax caches traces per static
+configuration, alternating scenes step after step re-uses each
+artifact's already-compiled trace — mixing scenes never retraces. Completed work items surface through `poll()` before the
 full request drains (streaming partial frames).
 
 Two seams make the whole scheduler drivable from tests with zero real
@@ -27,8 +27,9 @@ renders, and they are the design constraint on this layer:
     defaults to `FusedDeviceStep`, whose `step_items` renders each slot
     through the real fused integer render (a pose-cache tier with
     grow-on-overflow sample budgets, or for a proposal-sampled Nerfacto
-    artifact its two programs a slot at fixed samples). A scripted fake
-    turns `step()` into a pure state transition.
+    artifact its two programs a slot at fixed samples) and syncs with the
+    device once a step. A scripted fake turns `step()` into a pure state
+    transition.
 
 `loader=` (scene -> artifact) serves cache misses; `size_fn=` prices an
 artifact for the byte budget (defaults to `resident_bytes()` where
@@ -185,22 +186,213 @@ class ArtifactCache:
 # ---------------------------------------------------------------------------
 # Default device step: the real fused integer render
 # ---------------------------------------------------------------------------
+@dataclasses.dataclass
+class _Pending:
+    """One dispatched slot: its un-fetched device outputs and, for a
+    budgeted march, the budget it ran at and its staged rays (an overflow
+    re-renders them)."""
+
+    color: object
+    need: object = None
+    budget: Optional[int] = None
+    staged: tuple = ()
+
+
+class _MarchRoute:
+    """Instant-NGP slots. Each resolves a pose-cache tier: cache-hit (the
+    rays fingerprint-match the cell's baked plan), warp (the pose deviates
+    within the plan's conservative coverage margin) or march (a miss; the
+    cell's use count decides whether to bake a plan for next time). A
+    march runs at the scene's sample budget and returns its TRUE active
+    count `need` beside its colors, so an overflowing slot grows the
+    budget (one retrace) and re-renders: no silently dropped samples, no
+    host-side mask pass."""
+
+    tiers = True
+
+    def __init__(self, stepper: "FusedDeviceStep", scene: str, artifact):
+        self.stepper, self.artifact = stepper, artifact
+        self.st = stepper._scene_state(scene, artifact)
+
+    def _march(self, ro_s, rd_s) -> _Pending:
+        from repro.nerf.fast_render import _slot_march_impl
+
+        s, art, st = self.stepper, self.artifact, self.st
+        budget = st["budget"]
+        with s.obs.span("render.dispatch"):
+            color, need = _slot_march_impl(
+                art.params, art.pack, st["spec"], art.occ, ro_s, rd_s,
+                cfg=art.cfg, rcfg=st["rcfg"], mode="fused", budget=budget,
+                use_pallas=s.cfg.use_pallas, early_stop=s.cfg.early_stop,
+            )
+        points = ro_s.shape[0] * st["rcfg"].n_samples
+        s._count_lookups(art.pack, points if budget is None
+                         else min(budget, points))
+        if budget is None:
+            return _Pending(color)
+        return _Pending(color, need, budget, (ro_s, rd_s))
+
+    def dispatch(self, it: WorkItem, ro: np.ndarray,
+                 rd: np.ndarray) -> _Pending:
+        import jax.numpy as jnp
+
+        from repro.nerf import pose_cache as pc
+        from repro.nerf.fast_render import _slot_plan_impl, _slot_warp_impl
+
+        s, art, st = self.stepper, self.artifact, self.st
+        rec, cache = s.obs, s._pose_cache
+        with rec.span("render.slot", rid=it.rid, seq=it.seq) as sp:
+            with rec.span("render.stage"):
+                ro_s, rd_s = jnp.asarray(ro), jnp.asarray(rd)
+            key = getattr(it, "pose_key", None)
+            entry = plan = None
+            tier = "march"
+            if cache is not None and key is not None:
+                # Visits were counted at submit; a cell dropped between
+                # submit and step (scene eviction) restarts at one use.
+                entry = cache.get(key)
+                if entry is None:
+                    entry = cache.note_use(key)
+                plan = entry.plans.get(it.seq)
+                if plan is not None:
+                    if pc.ray_fingerprint(ro, rd) == plan.fp:
+                        tier = "hit"
+                    elif pc.warp_deviation(
+                        ro, rd, plan.ref_o, plan.ref_d, st["rcfg"],
+                    ) <= plan.margin:
+                        tier = "warp"
+            sp.set(tier=tier)
+            kw = dict(
+                cfg=art.cfg, rcfg=st["rcfg"], mode="fused",
+                use_pallas=s.cfg.use_pallas, early_stop=s.cfg.early_stop,
+            )
+            if tier == "hit":
+                cache.hits += 1
+                with rec.span("render.dispatch"):
+                    out = _slot_plan_impl(
+                        art.params, art.pack, st["spec"], art.occ,
+                        ro_s, rd_s, plan.plan_row, **kw,
+                    )
+                s._count_lookups(art.pack, plan.plan_row[0].shape[0])
+                return _Pending(out)
+            if tier == "warp":
+                cache.warps += 1
+                with rec.span("render.dispatch"):
+                    out = _slot_warp_impl(
+                        art.params, art.pack, st["spec"], art.occ,
+                        ro_s, rd_s, plan.inv_take, plan.take,
+                        plan.valid_cons, **kw,
+                    )
+                s._count_lookups(art.pack, plan.inv_take.shape[0])
+                return _Pending(out)
+            if entry is not None:
+                cache.misses += 1
+            pending = self._march(ro_s, rd_s)
+            if entry is not None and entry.uses >= s._pose_grid.build_after:
+                # The plan reads the rays and the occupancy grid, no slot
+                # output, so it is built while the device works.
+                with rec.span("pose.build"):
+                    cache.put_plan(key, it.seq, pc.build_warp_plan(
+                        art.occ, ro, rd, st["rcfg"], art.cfg,
+                        s._pose_grid.margin(art.occ),
+                    ))
+            return pending
+
+    def finish(self, p: _Pending, fetched) -> np.ndarray:
+        color, need = fetched
+        if p.budget is None:
+            return color
+        s, st = self.stepper, self.st
+        need = int(need)
+        while need > p.budget:
+            # A later slot of the step may fit a budget an earlier one
+            # grew; it re-renders at that budget without another retrace.
+            if need > st["budget"]:
+                cap = s.cfg.slot_rays * st["rcfg"].n_samples
+                grown = int(
+                    np.ceil(max(need * s.cfg.budget_headroom, need)
+                            / s._align) * s._align
+                )
+                st["budget"] = min(grown, cap)
+                st["retraces"] += 1
+            p = self._march(*p.staged)
+            color, need = s._fetch((p.color, p.need))
+            need = int(need)
+        s.obs.count("render.active_samples", need)
+        s.obs.count("render.budget_samples", p.budget)
+        return color
+
+
+class _ProposalRoute:
+    """Proposal-sampled slots (Nerfacto, `artifact.proposal_sampled`):
+    every ray is a fixed number of samples, so there is no budget and no
+    growth retrace, and no pose-cache tier, since plans index an
+    occupancy grid. A slot dispatches two programs back to back, the
+    proposal passes and then the main field's shading of the intervals
+    they place, with no host sync between them."""
+
+    tiers = False
+
+    def __init__(self, stepper: "FusedDeviceStep", scene: str, artifact):
+        self.stepper, self.artifact = stepper, artifact
+
+    def dispatch(self, it: WorkItem, ro: np.ndarray,
+                 rd: np.ndarray) -> _Pending:
+        import jax.numpy as jnp
+
+        from repro.nerf import fast_render as fr
+
+        s, art = self.stepper, self.artifact
+        rec, cfg = s.obs, art.cfg
+        kw = dict(cfg=cfg, use_pallas=s.cfg.use_pallas)
+        with rec.span("render.slot", rid=it.rid, seq=it.seq, tier="proposal"):
+            with rec.span("render.stage"):
+                ro_s, rd_s = jnp.asarray(ro), jnp.asarray(rd)
+            with rec.span("render.dispatch"):
+                edges = fr._slot_propose_impl(art.pack, ro_s, rd_s, **kw)
+            with rec.span("render.dispatch"):
+                out = fr._slot_shade_impl(
+                    art.pack, ro_s, rd_s, edges,
+                    early_stop=s.cfg.early_stop, **kw,
+                )
+            n = it.stop - it.start
+            rec.count("render.proposal_samples",
+                      n * cfg.proposal_samples_per_ray)
+            rec.count("render.shade_samples", n * cfg.shade_samples_per_ray)
+            R = ro.shape[0]  # the programs query every ray of the slot
+            for p, k in zip(art.pack.proposals,
+                            (cfg.n_initial,) + cfg.n_resampled[:-1]):
+                s._count_lookups(p, R * k)
+            s._count_lookups(art.pack.main, R * cfg.shade_samples_per_ray)
+            return _Pending(out)
+
+    def finish(self, p: _Pending, fetched) -> np.ndarray:
+        return fetched[0]
+
+
+def _route_class(artifact):
+    """The slot route of an artifact's model family (`artifact` may be
+    None: a scene not resident yet takes the march route)."""
+    if getattr(artifact, "proposal_sampled", False):
+        return _ProposalRoute
+    return _MarchRoute
+
+
 class FusedDeviceStep:
     """`step_items(scene, artifact, items, ro, rd) -> colors` through the
-    fused render path, one slot at a time.
+    fused render path, in two phases a step: dispatch every live slot's
+    program(s), then fetch all their outputs in one blocking transfer.
+
+    What a slot runs is its artifact's route, `_MarchRoute` (pose-cache
+    tiers, grow-on-overflow sample budget) or `_ProposalRoute` (Nerfacto's
+    two programs at fixed samples); `step_items` runs the same two phases
+    over either.
 
     Per-scene state (quant spec, eval rcfg, grow-on-overflow sample
     budget) lives HERE, not in the cache entry: a scene's budget survives
     eviction and reload, so re-admitting a hot scene does not re-pay its
     growth retraces. Derived spec/rcfg rebuild only when the artifact
     object actually changes (reload).
-
-    A proposal-sampled artifact (Nerfacto, `artifact.proposal_sampled`)
-    takes none of that: every ray is a fixed number of samples, so there
-    is no budget and no growth retrace, and no pose-cache tier, since
-    plans index an occupancy grid. Each of its slots runs two programs
-    back to back, the proposal passes and then the main field's shading,
-    with no host sync between them.
     """
 
     def __init__(self, cfg: EngineConfig, recorder: obs.Recorder):
@@ -261,7 +453,7 @@ class FusedDeviceStep:
         pose cache is disabled or the scene's resident `artifact` is
         proposal-sampled."""
         if (self._pose_cache is None or ro.shape[0] == 0
-                or getattr(artifact, "proposal_sampled", False)):
+                or not _route_class(artifact).tiers):
             return None
         from repro.nerf.pose_cache import pose_cell_key
 
@@ -310,176 +502,38 @@ class FusedDeviceStep:
         self.obs.count("hash.lookups", points * per_point)
         self.obs.count("hash.cell_lookups", points * cell_per_point)
 
-    def _march_slot(self, st, artifact, ro_s, rd_s) -> np.ndarray:
-        """Cache-miss tier for one padded slot, with grow-on-overflow:
-        the march impl returns the TRUE device active count, so an
-        overflowing slot grows the budget (one retrace) and re-renders —
-        no silently dropped samples, no host-side mask pass per step."""
-        from repro.nerf.fast_render import _slot_march_impl
+    def _fetch(self, outputs):
+        """Every given device output back on the host in one blocking
+        transfer (one `render.wait` span, one `render.fetches` count)."""
+        import jax
 
-        rec = self.obs
-        while True:
-            with rec.span("render.dispatch"):
-                color, need = _slot_march_impl(
-                    artifact.params, artifact.pack, st["spec"], artifact.occ,
-                    ro_s, rd_s, cfg=artifact.cfg, rcfg=st["rcfg"],
-                    mode="fused", budget=st["budget"],
-                    use_pallas=self.cfg.use_pallas,
-                    early_stop=self.cfg.early_stop,
-                )
-            budget = st["budget"]
-            points = ro_s.shape[0] * st["rcfg"].n_samples
-            self._count_lookups(artifact.pack, points if budget is None
-                                else min(budget, points))
-            with rec.span("render.wait"):
-                if budget is None:
-                    return np.asarray(color)
-                need = int(need)
-                if need <= budget:
-                    color = np.asarray(color)
-            if need <= budget:
-                rec.count("render.active_samples", need)
-                rec.count("render.budget_samples", budget)
-                return color
-            cap = self.cfg.slot_rays * st["rcfg"].n_samples
-            grown = int(
-                np.ceil(max(need * self.cfg.budget_headroom, need)
-                        / self._align) * self._align
-            )
-            st["budget"] = min(grown, cap)
-            st["retraces"] += 1
-
-    def _render_slot(self, st, artifact, it: WorkItem,
-                     ro: np.ndarray, rd: np.ndarray) -> np.ndarray:
-        """One live slot of `step_items` through its pose-cache tier."""
-        import jax.numpy as jnp
-
-        from repro.nerf.fast_render import _slot_plan_impl, _slot_warp_impl
-
-        rec = self.obs
-        with rec.span("render.slot", rid=it.rid, seq=it.seq) as sp:
-            kw = dict(
-                cfg=artifact.cfg, rcfg=st["rcfg"], mode="fused",
-                use_pallas=self.cfg.use_pallas, early_stop=self.cfg.early_stop,
-            )
-            with rec.span("render.stage"):
-                ro_s, rd_s = jnp.asarray(ro), jnp.asarray(rd)
-            cache = self._pose_cache
-            key = getattr(it, "pose_key", None)
-            entry = plan = None
-            tier = "march"
-            if cache is not None and key is not None:
-                from repro.nerf import pose_cache as pc
-
-                # Visits were counted at submit; a cell dropped between
-                # submit and step (scene eviction) restarts at one use.
-                entry = cache.get(key)
-                if entry is None:
-                    entry = cache.note_use(key)
-                plan = entry.plans.get(it.seq)
-                if plan is not None:
-                    if pc.ray_fingerprint(ro, rd) == plan.fp:
-                        tier = "hit"
-                    elif pc.warp_deviation(
-                        ro, rd, plan.ref_o, plan.ref_d, st["rcfg"],
-                    ) <= plan.margin:
-                        tier = "warp"
-                    else:
-                        plan = None  # drifted out of coverage: rebuild
-            sp.set(tier=tier)
-            if tier == "hit":
-                cache.hits += 1
-                with rec.span("render.dispatch"):
-                    out = _slot_plan_impl(
-                        artifact.params, artifact.pack, st["spec"],
-                        artifact.occ, ro_s, rd_s, plan.plan_row, **kw,
-                    )
-                self._count_lookups(artifact.pack, plan.plan_row[0].shape[0])
-            elif tier == "warp":
-                cache.warps += 1
-                with rec.span("render.dispatch"):
-                    out = _slot_warp_impl(
-                        artifact.params, artifact.pack, st["spec"],
-                        artifact.occ, ro_s, rd_s, plan.inv_take, plan.take,
-                        plan.valid_cons, **kw,
-                    )
-                self._count_lookups(artifact.pack, plan.inv_take.shape[0])
-            else:
-                if cache is not None and key is not None:
-                    cache.misses += 1
-                out = self._march_slot(st, artifact, ro_s, rd_s)
-                if (
-                    entry is not None
-                    and entry.uses >= self._pose_grid.build_after
-                ):
-                    from repro.nerf import pose_cache as pc
-
-                    with rec.span("pose.build"):
-                        cache.put_plan(key, it.seq, pc.build_warp_plan(
-                            artifact.occ, ro, rd, st["rcfg"],
-                            artifact.cfg, self._pose_grid.margin(artifact.occ),
-                        ))
-                return out
-            with rec.span("render.wait"):
-                return np.asarray(out)
-
-    def _propose_slot(self, artifact, it: WorkItem, ro: np.ndarray,
-                      rd: np.ndarray) -> np.ndarray:
-        """One live slot of a proposal-sampled artifact: the proposal
-        passes, then the main field's shading of the intervals they
-        place, dispatched back to back."""
-        import jax.numpy as jnp
-
-        from repro.nerf import fast_render as fr
-
-        rec, cfg = self.obs, artifact.cfg
-        kw = dict(cfg=cfg, use_pallas=self.cfg.use_pallas)
-        with rec.span("render.slot", rid=it.rid, seq=it.seq, tier="proposal"):
-            with rec.span("render.stage"):
-                ro_s, rd_s = jnp.asarray(ro), jnp.asarray(rd)
-            with rec.span("render.dispatch"):
-                edges = fr._slot_propose_impl(artifact.pack, ro_s, rd_s, **kw)
-            with rec.span("render.dispatch"):
-                out = fr._slot_shade_impl(
-                    artifact.pack, ro_s, rd_s, edges,
-                    early_stop=self.cfg.early_stop, **kw,
-                )
-            n = it.stop - it.start
-            rec.count("render.proposal_samples", n * cfg.proposal_samples_per_ray)
-            rec.count("render.shade_samples", n * cfg.shade_samples_per_ray)
-            R = ro.shape[0]  # the programs query every ray of the slot
-            for p, s in zip(artifact.pack.proposals,
-                            (cfg.n_initial,) + cfg.n_resampled[:-1]):
-                self._count_lookups(p, R * s)
-            self._count_lookups(artifact.pack.main,
-                                R * cfg.shade_samples_per_ray)
-            with rec.span("render.wait"):
-                return np.asarray(out)
+        with self.obs.span("render.wait"):
+            got = jax.device_get(outputs)
+        self.obs.count("render.fetches")
+        return got
 
     def step_items(
         self, scene: str, artifact, items: List[WorkItem],
         ro: np.ndarray, rd: np.ndarray,
     ) -> np.ndarray:
-        """Tiered per-slot render of one padded bucket.
+        """Two-phase render of one padded bucket.
 
-        Each live slot resolves to cache-hit (rays fingerprint-match the
-        cell's baked plan), warp (pose deviates within the plan's
-        conservative coverage margin), or march (miss; the cell's use
-        count decides whether to bake a plan for next time). Every tier
-        runs at the same fixed (slot_rays, 3) padded shape, so mixing
-        tiers within a bucket never retraces anything.
+        Phase 1 stages and dispatches every live slot in slot order and
+        fetches nothing, so the device runs one slot while the host stages
+        the next. Phase 2 brings back every slot's colors (and each
+        budgeted march slot's `need`) in one transfer; the route then
+        finishes each slot, re-rendering, blocking, a march slot that
+        overflowed the budget it ran at. Every tier runs at the same fixed
+        (slot_rays, 3) padded shape, so mixing tiers within a bucket never
+        retraces anything.
         """
-        if getattr(artifact, "proposal_sampled", False):
-            colors = np.zeros((ro.shape[0], ro.shape[1], 3), np.float32)
-            for slot, it in enumerate(items):
-                colors[slot] = self._propose_slot(artifact, it, ro[slot],
-                                                  rd[slot])
-            return colors
-        st = self._scene_state(scene, artifact)
-        S = ro.shape[0]
-        colors = np.zeros((S, ro.shape[1], 3), np.float32)
-        for slot, it in enumerate(items):
-            colors[slot] = self._render_slot(st, artifact, it, ro[slot], rd[slot])
+        route = _route_class(artifact)(self, scene, artifact)
+        pending = [route.dispatch(it, ro[slot], rd[slot])
+                   for slot, it in enumerate(items)]
+        fetched = self._fetch([(p.color, p.need) for p in pending])
+        colors = np.zeros((ro.shape[0], ro.shape[1], 3), np.float32)
+        for slot, (p, got) in enumerate(zip(pending, fetched)):
+            colors[slot] = route.finish(p, got)
         return colors
 
     # ------------------------------------------------------------------

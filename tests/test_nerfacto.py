@@ -269,11 +269,11 @@ def test_artifact_round_trip_keeps_codes_and_serves_exactly(tmp_path):
     # arithmetic differently where it sits in another vector lane.
     np.testing.assert_allclose(got, np.asarray(want), atol=1e-6)
     s = svc.stats()
-    slot_points = 6 * 128  # 700 rays in 6 slots of 128
+    slot_points = 6 * 128  # 700 rays in 6 slots of 128, 3 steps of 2
     assert s["trace"]["counters"] == {
         "render.proposal_samples": 700 * 24, "render.shade_samples": 700 * 8,
         "hash.lookups": slot_points * (16 * 16 + 8 * 16 + 8 * 32),
-        "hash.cell_lookups": slot_points * 8 * 8}
+        "hash.cell_lookups": slot_points * 8 * 8, "render.fetches": 3}
     spans = s["trace"]["spans"]
     assert "pose.key" not in spans
     assert spans["render.dispatch"]["count"] == 2 * spans["render.slot"]["count"]
@@ -301,3 +301,40 @@ def test_engine_counts_hash_lookups_per_proposal_slot(monkeypatch):
     per_slot = 64 * (16 * 2 * 8 + 8 * 2 * 8 + 8 * 4 * 8)
     assert c["hash.lookups"] == 3 * per_slot
     assert c["hash.cell_lookups"] == 3 * 64 * 8 * 1 * 8
+
+
+def test_engine_dispatches_every_proposal_slot_before_one_fetch(monkeypatch):
+    """Zero-render: with both slot programs stubbed to record each
+    dispatch and the fetch wrapped to record each fetch, every step
+    dispatches both programs of each of its slots before its one fetch,
+    and `render.fetches` counts one a step."""
+    from repro import hero
+    from repro.hero import ServeConfig
+
+    log = []
+
+    def propose(pack, o, d, **kw):
+        log.append("propose")
+        return o
+
+    def shade(pack, o, d, edges, **kw):
+        log.append("shade")
+        return jnp.zeros_like(o)
+
+    monkeypatch.setattr(fr, "_slot_propose_impl", propose)
+    monkeypatch.setattr(fr, "_slot_shade_impl", shade)
+    svc = hero.serve(quantized_artifact(0), ServeConfig(slots=2, slot_rays=64))
+    stepper = svc.engine._stepper
+    fetch = stepper._fetch
+
+    def logged_fetch(outputs):
+        log.append("fetch")
+        return fetch(outputs)
+
+    monkeypatch.setattr(stepper, "_fetch", logged_fetch)
+    log.clear()  # `hero.serve` warms the engine up
+    svc.render(*map(np.asarray, rays(n=150)))  # 3 slots: steps of 2 and 1
+    P, S, F = "propose", "shade", "fetch"
+    assert log == [P, S, P, S, F, P, S, F]
+    s = svc.engine.stats()
+    assert s["trace"]["counters"]["render.fetches"] == s["device_steps"] == 2

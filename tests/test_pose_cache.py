@@ -326,8 +326,9 @@ def test_engine_fresh_poses_build_nothing(tiny_artifact):
 
 def test_engine_tier_counters_and_spans(tiny_artifact):
     """The engine's spans name each slot's work: two visits march (the
-    second bakes plans), the third hits; only march slots fetch and count
-    their active samples."""
+    second bakes plans), the third hits; each visit is one step of 3
+    slots, which fetches once; only march slots count their active
+    samples."""
     scene = tiny_artifact.scene
     eng = _engine(tiny_artifact)
     ro, rd = _orbit(0.7, 0.12)
@@ -339,14 +340,17 @@ def test_engine_tier_counters_and_spans(tiny_artifact):
     pc = eng.stats()["pose_cache"]
     assert (pc["misses"], pc["hits"], pc["warps"]) == (6, 3, 0)
     assert sp["pose.build"]["count"] == 3 and sp["pose.key"]["count"] == 3
-    assert sp["render.slot"]["count"] == 9 and sp["render.wait"]["count"] == 9
+    assert sp["render.slot"]["count"] == 9
+    assert (sp["render.wait"]["count"] == c["render.fetches"]
+            == eng.stats()["device_steps"] == 3)
     assert c["render.budget_samples"] == 6 * eng.budget
     assert c["render.active_samples"] <= c["render.budget_samples"]
 
 
 def test_engine_without_budget_counts_no_samples(tiny_artifact):
-    """With no sample budget the march slot fetches only its colors, and
-    the fill counters, which need a budget, stay empty."""
+    """With no sample budget the march slots fetch only their colors, all
+    3 slots of the one step in one fetch, and the fill counters, which
+    need a budget, stay empty."""
     scene = tiny_artifact.scene
     eng = _engine(tiny_artifact, budget=None, pose_cache=False)
     ro, rd = _orbit(0.7, 0.12)
@@ -354,8 +358,10 @@ def test_engine_without_budget_counts_no_samples(tiny_artifact):
     eng.render(ro, rd, scene=scene)
     tr = eng.stats()["trace"]
     assert eng.budget is None
-    assert tr["spans"]["render.wait"]["count"] == 3
-    assert set(tr["counters"]) == {"hash.lookups", "hash.cell_lookups"}
+    assert tr["spans"]["render.wait"]["count"] == 1
+    assert set(tr["counters"]) == {"hash.lookups", "hash.cell_lookups",
+                                   "render.fetches"}
+    assert tr["counters"]["render.fetches"] == 1
 
 
 @pytest.mark.parametrize("budget", ["auto", None])
@@ -383,3 +389,179 @@ def test_engine_counts_hash_lookups_per_march_slot(tiny_artifact, monkeypatch,
     points = eng.budget or 64 * tiny_artifact.rcfg.n_samples
     assert c["hash.lookups"] == 3 * points * 4 * 8
     assert c["hash.cell_lookups"] == 3 * points * 1 * 8
+
+
+def _visit(eng, ro, rd):
+    """One request's colors through `eng`, and the pose-cache tier counts
+    it moved."""
+    before = dict(eng._stepper.pose_stats())
+    got = eng.render(ro, rd, scene=eng.scenes[0])
+    after = eng._stepper.pose_stats()
+    return got, {k: after[k] - before[k] for k in ("misses", "hits", "warps")}
+
+
+def _slot_alone(stepper, art, tier, ro, rd, plan=None):
+    """One slot rendered by itself through its tier's program and fetched
+    at once."""
+    import jax.numpy as jnp
+
+    from repro.nerf import fast_render as fr
+
+    st = stepper._state[art.scene]
+    kw = dict(cfg=art.cfg, rcfg=st["rcfg"], mode="fused",
+              use_pallas=stepper.cfg.use_pallas,
+              early_stop=stepper.cfg.early_stop)
+    args = (art.params, art.pack, st["spec"], art.occ,
+            jnp.asarray(ro), jnp.asarray(rd))
+    if tier == "march":
+        return np.asarray(
+            fr._slot_march_impl(*args, budget=st["budget"], **kw)[0])
+    if tier == "hit":
+        return np.asarray(fr._slot_plan_impl(*args, plan.plan_row, **kw))
+    return np.asarray(fr._slot_warp_impl(
+        *args, plan.inv_take, plan.take, plan.valid_cons, **kw))
+
+
+@pytest.mark.parametrize("tier", ["march", "hit", "warp"])
+def test_engine_full_bucket_matches_slots_rendered_alone(tiny_artifact,
+                                                         tier):
+    """A full bucket of 4 slots of one tier, dispatched together and
+    fetched once, serves byte for byte what each slot serves rendered by
+    itself through its tier's program and fetched at once. The pose and
+    the tier progression are `test_engine_tier_progression_and_parity`'s:
+    the first visit marches, the second bakes plans, the third hits, an
+    in-cell jitter warps."""
+    scene = tiny_artifact.scene
+    eng = _engine(tiny_artifact)
+    stepper = eng._stepper
+    ro, rd = _orbit(0.3, 0.11, hw=16)  # 256 rays: 4 full slots, one step
+    if tier != "march":
+        _visit(eng, ro, rd)
+        _visit(eng, ro, rd)
+    got = None
+    if tier == "warp":
+        key0 = stepper.pose_key(scene, ro, rd)
+        for eps in (1e-4, -1e-4, 5e-5, -5e-5):
+            ro_j = ro + np.float32(eps)
+            if stepper.pose_key(scene, ro_j, rd) != key0:
+                continue
+            got, moved = _visit(eng, ro_j, rd)
+            if moved["warps"] == 4:
+                ro = ro_j
+                break
+            got = None
+        assert got is not None, "no jitter put every slot in the warp tier"
+    else:
+        got, moved = _visit(eng, ro, rd)
+        assert moved[{"march": "misses", "hit": "hits"}[tier]] == 4
+    plans = (stepper._pose_cache.get(stepper.pose_key(scene, ro, rd)).plans
+             if tier != "march" else {})
+    for k in range(4):
+        s = slice(64 * k, 64 * (k + 1))
+        alone = _slot_alone(stepper, tiny_artifact, tier, ro[s], rd[s],
+                            plans.get(k))
+        np.testing.assert_array_equal(got[s], alone)
+
+
+def _bucket_rays(rng):
+    """Four 64-ray slots that start inside the scene box. In each slot
+    the share `corner` of the rays leaves a corner of the box along its
+    diagonal (about 4.4 of a ray's 8 samples active); the rest leave its
+    center in random directions (about 1.8 active)."""
+    ro, rd = [], []
+    for corner in (0.25, 1.0, 0.0, 0.5):
+        k = int(64 * corner)
+        o = np.zeros((64, 3))
+        d = rng.normal(size=(64, 3))
+        o[:k] = -0.45 + rng.uniform(-0.03, 0.03, (k, 3))
+        d[:k] = 1.0 + rng.uniform(-0.2, 0.2, (k, 3))
+        ro.append(o)
+        rd.append(d / np.linalg.norm(d, axis=1, keepdims=True))
+    return (np.concatenate(ro).astype(np.float32),
+            np.concatenate(rd).astype(np.float32))
+
+
+def test_engine_overflowing_slots_of_one_step_regrow_and_rerender(
+        tiny_artifact):
+    """An undersized budget (128 samples) on one step whose slots need
+    more: every slot over the budget it ran at re-renders with a fetch of
+    its own, the budget grows by the engine's rule only where a slot
+    overflows what an earlier slot of the step grew it to (each growth
+    one retrace), and the colors equal those of an engine with no
+    budget."""
+    import jax.numpy as jnp
+
+    from repro.nerf.fast_render import _slot_march_impl
+
+    art, scene = tiny_artifact, tiny_artifact.scene
+    ro, rd = _bucket_rays(np.random.RandomState(0))
+    eng = _engine(art, budget=128, pose_cache=False)
+    plain = _engine(art, budget=None, pose_cache=False)
+    st = eng._stepper._scene_state(scene, art)
+    needs = [int(_slot_march_impl(
+        art.params, art.pack, st["spec"], art.occ,
+        jnp.asarray(ro[64 * k:64 * (k + 1)]), jnp.asarray(rd[64 * k:64 * (k + 1)]),
+        cfg=art.cfg, rcfg=st["rcfg"], mode="fused", budget=None,
+        use_pallas=eng.cfg.use_pallas, early_stop=eng.cfg.early_stop)[1])
+        for k in range(4)]
+    budget, growths = 128, 0
+    for n in needs:  # the growth rule, slot by slot
+        if n > budget:
+            grown = np.ceil(max(n * eng.cfg.budget_headroom, n) / 128) * 128
+            budget, growths = min(int(grown), 64 * st["rcfg"].n_samples), growths + 1
+    overflows = sum(n > 128 for n in needs)
+    assert overflows >= 3 and growths >= 2 and overflows > growths
+
+    eng.reset_stats()
+    got = eng.render(ro, rd, scene=scene)
+    np.testing.assert_array_equal(got, plain.render(ro, rd, scene=scene))
+    s = eng.stats()
+    c = s["trace"]["counters"]
+    assert s["budget_retraces"] == growths and eng.budget == budget
+    assert (c["render.fetches"] == s["trace"]["spans"]["render.wait"]["count"]
+            == s["device_steps"] + overflows)
+    assert c["render.active_samples"] == sum(needs)
+    assert c["render.active_samples"] <= c["render.budget_samples"]
+
+
+def test_engine_dispatches_every_slot_before_one_fetch(tiny_artifact,
+                                                       monkeypatch):
+    """Zero-render: with the march program stubbed to record each dispatch
+    and the fetch wrapped to record each fetch, every step dispatches all
+    its slots before its one fetch. The 2nd and 3rd slots of the first
+    step overflow the budget they ran at: each then re-renders and
+    fetches on its own, and the budget grows once, since the 3rd fits
+    what the 2nd grew it to."""
+    import jax.numpy as jnp
+
+    from repro.nerf import fast_render as fr
+
+    log, calls = [], []
+
+    def march(params, pack, spec, occ, ro, rd, *, budget, **kw):
+        log.append("dispatch")
+        calls.append(budget)
+        need = budget + 1 if len(calls) in (2, 3) else 0
+        return jnp.zeros_like(ro), jnp.int32(need)
+
+    monkeypatch.setattr(fr, "_slot_march_impl", march)
+    eng = _engine(tiny_artifact, budget=128, pose_cache=False)
+    stepper = eng._stepper
+    fetch = stepper._fetch
+
+    def logged_fetch(outputs):
+        log.append("fetch")
+        return fetch(outputs)
+
+    monkeypatch.setattr(stepper, "_fetch", logged_fetch)
+    scene = tiny_artifact.scene
+    eng.submit(*_orbit(0.7, 0.12, hw=16), scene=scene)  # 4 slots
+    eng.submit(*_orbit(0.7, 0.12), scene=scene)  # 3 slots
+    eng.reset_stats()
+    eng.drain()
+    D, F = "dispatch", "fetch"
+    assert log == [D, D, D, D, F, D, F, D, F, D, D, D, F]
+    assert calls == [128] * 4 + [256] * 5
+    s = eng.stats()
+    assert s["device_steps"] == 2 and s["budget_retraces"] == 1
+    assert s["trace"]["counters"]["render.fetches"] == 2 + 2
